@@ -78,7 +78,9 @@ json.dump({
 
 def _run_tp(tp: int) -> dict:
     repo = Path(__file__).resolve().parent.parent
-    env = dict(os.environ)
+    # a CPU-mesh check by design: the child never competes for a TPU
+    # that this host (or the parent process) may hold
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = str(repo / "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     r = subprocess.run(

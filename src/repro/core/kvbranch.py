@@ -382,6 +382,22 @@ class KVBranchManager:
                 self._c_prefix_misses.inc()
             return pages, covered
 
+    def clear_prefix_cache(self) -> int:
+        """Drop every prefix-cache entry and the page reference it holds.
+
+        Returns the number of entries dropped.  Pages a live table still
+        shares stay allocated; once every sequence is released as well,
+        the whole pool is free again.
+        """
+        with self._tree.lock:
+            pages = list(self._prefix_pages.values())
+            self._prefix_pages.clear()
+            self._prefix_lru.clear()
+            self._c_prefix_evictions.inc(len(pages))
+            self._decref(pages)
+            self._g_prefix_shared.set(0)
+            return len(pages)
+
     def register_prefix(self, seq_id: int, tokens: Sequence[int]) -> int:
         """Publish ``seq_id``'s prompt pages for cross-request sharing.
 

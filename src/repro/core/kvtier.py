@@ -41,8 +41,8 @@ class KVSnapshot:
 
     Pages are stored in the pool's native dtype (bf16 bytes or int8 +
     per-page scales) — re-quantizing on restore would drift tokens.
-    Shapes: ``k_pages``/``v_pages`` are ``[layers, n_pages, page_size,
-    kv_heads, head_dim]``; scales (int8 pools only) are ``[layers,
+    Shapes: ``k_pages``/``v_pages`` are ``[layers, n_pages, kv_heads,
+    page_size, head_dim]``; scales (int8 pools only) are ``[layers,
     n_pages, kv_heads]``.
     """
 

@@ -1,7 +1,6 @@
 """Distribution substrate: mesh/axis conventions, sharding rules,
 custom collectives (compression, overlap)."""
 
-from repro.distributed.compat import shard_map
 from repro.distributed.mesh import (
     ParallelPlan,
     SINGLE_DEVICE,
@@ -20,5 +19,5 @@ from repro.distributed.sharding import (
 __all__ = [
     "ParallelPlan", "SINGLE_DEVICE", "batch_spec", "kv_page_spec",
     "param_shardings", "serve_param_specs", "serving_mesh",
-    "serving_plan", "shard_map", "shard_params", "state_shardings",
+    "serving_plan", "shard_params", "state_shardings",
 ]

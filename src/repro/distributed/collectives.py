@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
 from repro.optim.compress import int8_compress, int8_decompress
 
 
@@ -103,11 +102,11 @@ def allreduce_grads_over_pod(grads: Any, mesh: Mesh, *,
         return jax.lax.pmean(g, "pod")
 
     def one(g):
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=P(*((None,) * g.ndim)),
             out_specs=P(*((None,) * g.ndim)),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(g)
 

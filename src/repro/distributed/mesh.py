@@ -11,12 +11,24 @@ be written once and run single-device (tests), single-pod, or multi-pod.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from dataclasses import dataclass
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence[Any]] = None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    The model places arrays through sharding constraints and
+    ``shard_map`` specs, which refer only to auto axes; JAX's own default
+    is ``Explicit`` axes.  Every mesh of this repo is made here.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         (AxisType.Auto,) * len(axes), devices=devices)
 
 
 @dataclass(frozen=True)
@@ -93,7 +105,7 @@ def serving_mesh(tp: int) -> Mesh:
     if tp > len(jax.devices()):
         raise ValueError(
             f"tp={tp} exceeds the {len(jax.devices())} visible devices")
-    return jax.make_mesh((tp,), ("tp",))
+    return make_mesh((tp,), ("tp",))
 
 
 def serving_plan(mesh: Optional[Mesh]) -> ParallelPlan:

@@ -265,7 +265,7 @@ def serve_param_specs(cfg: ArchConfig, plan: ParallelPlan,
 
 
 def kv_page_spec(plan: ParallelPlan) -> P:
-    """Spec for the paged KV pools ``[L, n_pages, page, kv, hd]``.
+    """Spec for the paged KV pools ``[L, n_pages, kv, page, hd]``.
 
     Pages shard on the **kv-head dim**: a page id means the same thing
     on every shard, so the host-side block tables, refcounts and CoW
@@ -273,4 +273,4 @@ def kv_page_spec(plan: ParallelPlan) -> P:
     operation plus (at most) one fused ``_copy_pages`` dispatch, and
     each shard copies only its slice of the faulted page.
     """
-    return P(None, None, None, plan.tp_axis, None)
+    return P(None, None, plan.tp_axis, None, None)

@@ -35,7 +35,7 @@ from repro.kernels.select import resolve_impl
 @partial(jax.jit, static_argnames=("impl",))
 def paged_attention(
     q: jax.Array,            # [b, kv, g, hd]
-    k_pages: jax.Array,      # [n_pages, page, kv, hd]
+    k_pages: jax.Array,      # [n_pages, kv, page, hd]
     v_pages: jax.Array,
     block_tables: jax.Array, # [b, max_pages] int32
     lengths: jax.Array,      # [b] int32
@@ -60,7 +60,7 @@ def paged_chunk_attention(
     q: jax.Array,            # [b, t, kv, g, hd]
     k_new: jax.Array,        # [b, t, kv, hd]
     v_new: jax.Array,
-    k_pages: jax.Array,      # [n_pages, page, kv, hd] (int8 if quantized)
+    k_pages: jax.Array,      # [n_pages, kv, page, hd] (int8 if quantized)
     v_pages: jax.Array,
     block_tables: jax.Array, # [b, max_pages] int32
     lengths: jax.Array,      # [b] int32 — cached length (chunk excluded)

@@ -8,10 +8,15 @@ import jax.numpy as jnp
 import jax
 
 
+def _token_major(pages: jnp.ndarray) -> jnp.ndarray:
+    """[..., kv, page, hd] gathered pages -> [..., page, kv, hd]."""
+    return jnp.swapaxes(pages, -3, -2)
+
+
 def paged_attention_ref(
     q: jnp.ndarray,            # [b, kv, g, hd]
-    k_pages: jnp.ndarray,      # [n_pages, page, kv, hd]
-    v_pages: jnp.ndarray,      # [n_pages, page, kv, hd]
+    k_pages: jnp.ndarray,      # [n_pages, kv, page, hd]
+    v_pages: jnp.ndarray,      # [n_pages, kv, page, hd]
     block_tables: jnp.ndarray, # [b, max_pages] int32 (pad = anything)
     lengths: jnp.ndarray,      # [b] int32
 ) -> jnp.ndarray:
@@ -20,13 +25,13 @@ def paged_attention_ref(
     Returns [b, kv, g, hd].
     """
     b, kv, g, hd = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     max_pages = block_tables.shape[1]
     s = max_pages * page
 
-    # dense gather of each sequence's pages: [b, max_pages, page, kv, hd]
-    k = k_pages[block_tables].reshape(b, s, kv, hd)
-    v = v_pages[block_tables].reshape(b, s, kv, hd)
+    # dense gather of each sequence's pages: [b, max_pages * page, kv, hd]
+    k = _token_major(k_pages[block_tables]).reshape(b, s, kv, hd)
+    v = _token_major(v_pages[block_tables]).reshape(b, s, kv, hd)
 
     scale = 1.0 / math.sqrt(hd)
     scores = jnp.einsum("bkgh,bskh->bkgs", q, k,
@@ -42,7 +47,7 @@ def paged_chunk_attention_ref(
     q: jnp.ndarray,            # [b, t, kv, g, hd]
     k_new: jnp.ndarray,        # [b, t, kv, hd] — chunk K, not in the pool
     v_new: jnp.ndarray,
-    k_pages: jnp.ndarray,      # [n_pages, page, kv, hd] (int8 if quantized)
+    k_pages: jnp.ndarray,      # [n_pages, kv, page, hd] (int8 if quantized)
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray, # [b, max_pages] int32
     lengths: jnp.ndarray,      # [b] int32 — cached length (chunk excluded)
@@ -58,20 +63,20 @@ def paged_chunk_attention_ref(
     for the ``t`` inline tokens.  Returns [b, t, kv, g, hd].
     """
     b, t, kv, g, hd = q.shape
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     max_pages = block_tables.shape[1]
     s = max_pages * page
 
     tables = block_tables
     if page_map is not None:
         tables = page_map[block_tables]            # resolve CoW redirects
-    k = k_pages[tables].astype(jnp.float32)        # [b, mp, page, kv, hd]
+    k = k_pages[tables].astype(jnp.float32)        # [b, mp, kv, page, hd]
     v = v_pages[tables].astype(jnp.float32)
     if k_scales is not None:
-        k = k * k_scales[tables][:, :, None, :, None]
-        v = v * v_scales[tables][:, :, None, :, None]
-    k = k.reshape(b, s, kv, hd)
-    v = v.reshape(b, s, kv, hd)
+        k = k * k_scales[tables][..., None, None]
+        v = v * v_scales[tables][..., None, None]
+    k = _token_major(k).reshape(b, s, kv, hd)
+    v = _token_major(v).reshape(b, s, kv, hd)
 
     scale = 1.0 / math.sqrt(hd)
     qf = q.astype(jnp.float32)

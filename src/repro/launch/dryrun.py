@@ -1,5 +1,8 @@
 import os
+# the dry run compiles for 512 placeholder CPU devices; it never takes
+# a TPU this host may have
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -241,6 +244,7 @@ def main(argv=None) -> int:
                     cmd = [sys.executable, "-m", "repro.launch.dryrun",
                            "--arch", arch, "--shape", shape,
                            "--mesh", mesh, "--out", str(out_dir)]
+                    # the child inherits this module's JAX_PLATFORMS=cpu
                     r = subprocess.run(cmd)
                     if r.returncode != 0:
                         failures.append((arch, shape, mesh))

@@ -8,6 +8,8 @@ from __future__ import annotations
 
 import jax
 
+from repro.distributed.mesh import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -18,7 +20,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     # the dry-run host exposes 512 placeholder devices; the single-pod
     # mesh uses the first 256
     devices = jax.devices()[:n]
-    return jax.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
 # TPU v5e hardware constants for the roofline model

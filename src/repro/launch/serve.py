@@ -9,6 +9,11 @@ pressure), then prints the session's procfs-style ``tree()`` view::
 
     python -m repro.launch.serve --arch paper-agentic --branches 3
 
+The model runs at its published widths and dtype (``--arch qwen2-1.5b``
+is the full 1.5B model in bf16).  CPU runs of a large arch pass
+``--reduced --dtype float32`` for a tiny same-family stand-in.  The
+exit code is non-zero when any request was not served.
+
 ``--tp N`` runs the decode hot loop tensor-parallel over an N-device
 serving mesh (DESIGN §11) — weights and KV pages shard, branch
 bookkeeping stays host-side, and the served tokens are identical to
@@ -33,6 +38,8 @@ import dataclasses
 
 import jax
 import numpy as np
+
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def main(argv=None) -> int:
@@ -60,28 +67,38 @@ def main(argv=None) -> int:
                          "get the default class)")
     ap.add_argument("--num-pages", type=int, default=1024,
                     help="KV page-pool size (default 1024)")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve a tiny same-family stand-in of --arch "
+                         "(CPU runs); default is the published widths")
+    ap.add_argument("--dtype", default=None,
+                    help="parameter and activation dtype (CPU runs pass "
+                         "float32); default is the config's own")
     ap.add_argument("--no-prefix-cache", action="store_true",
                     help="disable cross-request KV prefix sharing "
                          "(on by default: identical prompt prefixes "
                          "share read-only CoW pages, so best-of-N from "
                          "N users costs one prefill)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     from repro.api import BranchSession
     from repro.configs import get_config, reduced
+    from repro.distributed.mesh import serving_mesh
     from repro.explore_ctx import ExplorationDriver, best_of_n
     from repro.models.model import Model
     from repro.obs import Observability
-    from repro.runtime.serve_loop import ServeEngine
+    from repro.runtime.serve_loop import ServeEngine, init_serve_params
 
     cfg = get_config(args.arch)
-    if cfg.param_count() > 1e8:  # big archs run reduced on CPU demo
+    if args.reduced:
         cfg = reduced(cfg)
-    cfg = dataclasses.replace(cfg, dtype="float32")
+    if args.dtype:
+        cfg = dataclasses.replace(cfg, dtype=args.dtype)
     model = Model(cfg, attn_chunk=8, remat=False)
-    params = model.init(jax.random.PRNGKey(0))
+    mesh = serving_mesh(args.tp) if args.tp else None
+    params = init_serve_params(model, jax.random.PRNGKey(0), mesh)
     engine = ServeEngine(model, params, num_pages=args.num_pages,
-                         page_size=8, max_pages_per_seq=64, tp=args.tp,
+                         page_size=8, max_pages_per_seq=64, mesh=mesh,
                          prefix_cache=not args.no_prefix_cache,
                          obs=Observability(trace=args.trace is not None))
     session = BranchSession(engine, max_batch=args.max_batch, seed=1)
@@ -106,9 +123,11 @@ def main(argv=None) -> int:
     # per-request (as the pre-driver demo did) and serve the rest
     driver.run(raise_errors=False)
 
+    failed = 0
     for r, (exp, prompt) in enumerate(prompts.items()):
         if exp.error is not None:
-            print(f"request {r}: not served ({exp.error}); skipped")
+            print(f"request {r}: not served ({exp.error})")
+            failed += 1
             continue
         res = exp.result
         scores = [f"{s:.1f}" for s in res.stats.get("scores", [])]
@@ -122,7 +141,7 @@ def main(argv=None) -> int:
     if args.trace:
         session.trace(args.trace)
         print(f"wrote {args.trace} — open at https://ui.perfetto.dev")
-    return 0
+    return 1 if failed else 0
 
 
 def _parse_tenants(spec):

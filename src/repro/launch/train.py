@@ -14,8 +14,12 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import tempfile
 
 import jax
+
+from repro.launch.compile_cache import configure_compile_cache
 
 
 def main(argv=None) -> int:
@@ -26,7 +30,8 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=1000)
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--ckpt-dir", default="/tmp/branchx-ckpt")
+    ap.add_argument("--ckpt-dir", default=os.path.join(
+        tempfile.gettempdir(), "branchx-ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--compress-grads", default=None,
                     choices=[None, "int8", "topk"])
@@ -35,6 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--distributed", action="store_true",
                     help="initialize jax.distributed (TPU pods)")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.distributed:
         jax.distributed.initialize()

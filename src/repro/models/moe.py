@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.distributed.compat import shard_map
 from repro.models.layers import dense_init
 
 Params = Dict[str, Any]
@@ -177,12 +176,12 @@ def moe_block(
         P(tp_axis, None, None),
     )
     out_specs = (P(*(dp + (None, None))), P())
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(local_fn),
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     if wg is None:
         wg = jnp.zeros((cfg.num_experts, 1, 1), x.dtype)  # placeholder

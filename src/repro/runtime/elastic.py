@@ -16,7 +16,7 @@ import jax
 from jax.sharding import Mesh
 
 from repro.configs.base import ArchConfig
-from repro.distributed.mesh import ParallelPlan, plan_from_mesh
+from repro.distributed.mesh import ParallelPlan, make_mesh, plan_from_mesh
 from repro.distributed.sharding import param_shardings
 
 
@@ -37,12 +37,12 @@ def plan_mesh(devices: Optional[Sequence[Any]] = None,
     n = len(devices)
     if multi_pod and n % 2 == 0 and n >= 4:
         data, model = factor_mesh(n // 2, prefer_model)
-        mesh = jax.make_mesh((2, data, model), ("pod", "data", "model"),
-                             devices=devices)
+        mesh = make_mesh((2, data, model), ("pod", "data", "model"),
+                         devices=devices)
     else:
         data, model = factor_mesh(n, prefer_model)
-        mesh = jax.make_mesh((data, model), ("data", "model"),
-                             devices=devices)
+        mesh = make_mesh((data, model), ("data", "model"),
+                         devices=devices)
     return plan_from_mesh(mesh)
 
 

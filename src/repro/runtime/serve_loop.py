@@ -2,7 +2,8 @@
 
 The paper's serving workload as a first-class engine feature:
 
-* KV lives in fixed-size **pages** ([L, n_pages, page, kv, hd] pools);
+* KV lives in fixed-size **pages** ([L, n_pages, kv, page, hd] pools,
+  head-major within a page so the kernel's page block tiles on TPU);
   sequences hold block tables managed by :class:`KVBranchManager`.
 * ``fork(seq, n)`` creates N generation branches sharing every page
   (CoW); the first append to a shared tail page triggers a single-page
@@ -47,7 +48,7 @@ the per-sequence state domains.
 or ``mesh=`` rebases the hot loop onto a tensor-parallel device mesh —
 weights shard per the training rules (heads / d_ff / experts over the
 tp axis), the KV pools shard on the **kv-head dim**, and the decode
-step runs under one compat-shimmed ``shard_map`` so a step is still one
+step runs under one ``jax.shard_map`` so a step is still one
 device dispatch.  All branch bookkeeping (block tables, refcounts, the
 lifecycle tree, token tails) is host-side integer metadata and stays
 replicated/device-agnostic; fork/commit cost does not change with mesh
@@ -72,7 +73,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.core import KVBranchManager
 from repro.core.kvtier import KVSnapshot, KVTierStore
-from repro.distributed.compat import shard_map
 from repro.distributed.mesh import ParallelPlan, serving_mesh, serving_plan
 from repro.distributed.sharding import kv_page_spec, serve_param_specs
 from repro.kernels.paged_attention.ops import (
@@ -120,7 +120,7 @@ def _ffn(cfg: ArchConfig, lp: Any, x: jax.Array, combine,
 def _decode_body(
     cfg: ArchConfig,
     params: Any,
-    k_pages: jax.Array,       # [L, n_pages, page, kv(_local), hd]
+    k_pages: jax.Array,       # [L, n_pages, kv(_local), page, hd]
     v_pages: jax.Array,
     block_tables: jax.Array,  # [b, max_pages]
     lengths: jax.Array,       # [b] length BEFORE this token
@@ -153,8 +153,8 @@ def _decode_body(
         x = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
         q, k, v = L.qkv_project(cfg, lp["attn"], x, lengths[:, None])
         # write this token's K/V into its (possibly CoW'd) page slot
-        kp = kp.at[slot_pages, slot_offsets].set(k[:, 0])
-        vp = vp.at[slot_pages, slot_offsets].set(v[:, 0])
+        kp = kp.at[slot_pages, :, slot_offsets].set(k[:, 0])
+        vp = vp.at[slot_pages, :, slot_offsets].set(v[:, 0])
         # heads are kv-major (head = kv * g + g_idx), so a contiguous
         # head shard is a contiguous kv-head shard: local shapes fall
         # out of the projection weights
@@ -178,7 +178,7 @@ def _decode_body(
 def paged_decode_step(
     cfg: ArchConfig,
     params: Any,
-    k_pages: jax.Array,       # [L, n_pages, page, kv, hd]
+    k_pages: jax.Array,       # [L, n_pages, kv, page, hd]
     v_pages: jax.Array,
     block_tables: jax.Array,  # [b, max_pages]
     lengths: jax.Array,       # [b] length BEFORE this token
@@ -204,11 +204,29 @@ def serve_specs(cfg: ArchConfig, plan: ParallelPlan, params: Any) -> Any:
     return specs
 
 
+def init_serve_params(model: Model, key: jax.Array,
+                      mesh: Optional[Mesh] = None) -> Any:
+    """Random parameters made directly in their serving placement.
+
+    Under a mesh every leaf is created in its :func:`serve_specs`
+    sharding, so a model sized for the whole mesh never has to fit on
+    one device first; without one this is ``model.init`` compiled once.
+    """
+    if mesh is None:
+        return jax.jit(model.init)(key)
+    shapes = jax.eval_shape(model.init, key)
+    specs = serve_specs(model.cfg, serving_plan(mesh), shapes)
+    shardings = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda s: isinstance(s, P))
+    return jax.jit(model.init, out_shardings=shardings)(key)
+
+
 def build_tp_decode_step(cfg: ArchConfig, plan: ParallelPlan, params: Any,
                          *, impl: str = "ref",
                          specs: Optional[Any] = None):
     """The tensor-parallel decode step: ``_decode_body`` under ONE
-    compat-shimmed ``shard_map`` so a whole fork/explore/commit step
+    ``jax.shard_map`` so a whole fork/explore/commit step
     still costs one device dispatch.
 
     Weights and KV pages arrive pre-sharded (the engine places them at
@@ -233,11 +251,11 @@ def build_tp_decode_step(cfg: ArchConfig, plan: ParallelPlan, params: Any,
                 logits, plan.tp_axis, axis=logits.ndim - 1, tiled=True)
         return logits, kp, vp
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_step, mesh=plan.mesh,
         in_specs=(specs, kv_spec, kv_spec, rep, rep, rep, rep, rep),
         out_specs=(rep, kv_spec, kv_spec),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -246,7 +264,7 @@ def build_tp_decode_step(cfg: ArchConfig, plan: ParallelPlan, params: Any,
 # fused decode fast path + speculative verify (DESIGN §12)
 # ---------------------------------------------------------------------------
 
-def _quant_token_write(pages: jax.Array,    # [n_pages, page, kv, hd] int8
+def _quant_token_write(pages: jax.Array,    # [n_pages, kv, page, hd] int8
                        scales: jax.Array,   # [n_pages, kv] f32
                        slot_pages: jax.Array,    # [b]
                        slot_offsets: jax.Array,  # [b]
@@ -262,11 +280,11 @@ def _quant_token_write(pages: jax.Array,    # [n_pages, page, kv, hd] int8
     b = tok.shape[0]
     sc = jnp.where(slot_offsets[:, None] == 0, 0.0,
                    scales[slot_pages])                     # [b, kv]
-    fp = pages[slot_pages].astype(jnp.float32) * sc[:, None, :, None]
-    fp = fp.at[jnp.arange(b), slot_offsets].set(tok.astype(jnp.float32))
+    fp = pages[slot_pages].astype(jnp.float32) * sc[:, :, None, None]
+    fp = fp.at[jnp.arange(b), :, slot_offsets].set(tok.astype(jnp.float32))
     need = jnp.max(jnp.abs(tok.astype(jnp.float32)), axis=-1) / 127.0
     nsc = jnp.maximum(jnp.maximum(sc, need), 1e-8)
-    q8 = jnp.clip(jnp.round(fp / nsc[:, None, :, None]),
+    q8 = jnp.clip(jnp.round(fp / nsc[:, :, None, None]),
                   -127, 127).astype(jnp.int8)
     return pages.at[slot_pages].set(q8), scales.at[slot_pages].set(nsc)
 
@@ -274,7 +292,7 @@ def _quant_token_write(pages: jax.Array,    # [n_pages, page, kv, hd] int8
 def _fused_decode_body(
     cfg: ArchConfig,
     params: Any,
-    k_pages: jax.Array,       # [L, n_pages, page, kv(_local), hd]
+    k_pages: jax.Array,       # [L, n_pages, kv(_local), page, hd]
     v_pages: jax.Array,
     block_tables: jax.Array,  # [b, max_pages]
     lengths: jax.Array,       # [b] length BEFORE this token
@@ -342,8 +360,8 @@ def _fused_decode_body(
             vp, vs = _quant_token_write(vp, vs, slot_pages, slot_offsets,
                                         v[:, 0])
         else:
-            kp = kp.at[slot_pages, slot_offsets].set(k[:, 0])
-            vp = vp.at[slot_pages, slot_offsets].set(v[:, 0])
+            kp = kp.at[slot_pages, :, slot_offsets].set(k[:, 0])
+            vp = vp.at[slot_pages, :, slot_offsets].set(v[:, 0])
         x = L.rms_norm(h, lp["ln2"], cfg.norm_eps)
         h = h + _ffn(cfg, lp, x, combine, axis_name)
         return h, ((kp, vp, ks, vs) if quant else (kp, vp))
@@ -546,7 +564,7 @@ def build_tp_fused_decode_step(cfg: ArchConfig, plan: ParallelPlan,
                                specs: Optional[Any] = None,
                                quantized: bool = False):
     """The tensor-parallel fused decode step — ``_fused_decode_body``
-    under ONE compat-shimmed ``shard_map``; CoW vectors replicate (page
+    under ONE ``jax.shard_map``; CoW vectors replicate (page
     ids are kv-head-agnostic), int8 scales shard with their pools."""
     if specs is None:
         specs = serve_specs(cfg, plan, params)
@@ -556,41 +574,26 @@ def build_tp_fused_decode_step(cfg: ArchConfig, plan: ParallelPlan,
     sc_spec = scale_spec(plan)
     rep = P()
 
-    if quantized:
-        def local_step(p, kp, vp, ks, vs, bt, lengths, slot_pages,
-                       slot_offsets, tokens, cow_src, cow_dst):
-            out = _fused_decode_body(
-                cfg, p, kp, vp, bt, lengths, slot_pages, slot_offsets,
-                tokens, cow_src, cow_dst, ks, vs, impl=impl,
-                axis_name=plan.tp_axis)
-            logits = out[0]
-            if gather_logits:
-                logits = jax.lax.all_gather(
-                    logits, plan.tp_axis, axis=logits.ndim - 1, tiled=True)
-            return (logits,) + out[1:]
+    scale_specs = (sc_spec, sc_spec) if quantized else ()
 
-        in_specs = (specs, kv_spec, kv_spec, sc_spec, sc_spec,
-                    rep, rep, rep, rep, rep, rep, rep)
-        out_specs = (rep, kv_spec, kv_spec, sc_spec, sc_spec)
-    else:
-        def local_step(p, kp, vp, bt, lengths, slot_pages, slot_offsets,
-                       tokens, cow_src, cow_dst):
-            out = _fused_decode_body(
-                cfg, p, kp, vp, bt, lengths, slot_pages, slot_offsets,
-                tokens, cow_src, cow_dst, impl=impl,
-                axis_name=plan.tp_axis)
-            logits = out[0]
-            if gather_logits:
-                logits = jax.lax.all_gather(
-                    logits, plan.tp_axis, axis=logits.ndim - 1, tiled=True)
-            return (logits,) + out[1:]
+    # int8 scales ride last, as in the single-device step's signature
+    def local_step(p, kp, vp, bt, lengths, slot_pages, slot_offsets,
+                   tokens, cow_src, cow_dst, *scales):
+        out = _fused_decode_body(
+            cfg, p, kp, vp, bt, lengths, slot_pages, slot_offsets,
+            tokens, cow_src, cow_dst, *scales, impl=impl,
+            axis_name=plan.tp_axis)
+        logits = out[0]
+        if gather_logits:
+            logits = jax.lax.all_gather(
+                logits, plan.tp_axis, axis=logits.ndim - 1, tiled=True)
+        return (logits,) + out[1:]
 
-        in_specs = (specs, kv_spec, kv_spec,
-                    rep, rep, rep, rep, rep, rep, rep)
-        out_specs = (rep, kv_spec, kv_spec)
+    in_specs = (specs, kv_spec, kv_spec) + (rep,) * 7 + scale_specs
+    out_specs = (rep, kv_spec, kv_spec) + scale_specs
 
-    fn = shard_map(local_step, mesh=plan.mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_step, mesh=plan.mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -606,32 +609,20 @@ def build_tp_verify_step(cfg: ArchConfig, plan: ParallelPlan, params: Any,
     kv_spec = kv_page_spec(plan)
     sc_spec = scale_spec(plan)
     rep = P()
+    scale_specs = (sc_spec, sc_spec) if quantized else ()
 
-    if quantized:
-        def local_step(p, kp, vp, ks, vs, bt, lengths, tokens):
-            logits = _verify_body(cfg, p, kp, vp, bt, lengths, tokens,
-                                  ks, vs, impl=impl,
-                                  axis_name=plan.tp_axis)
-            if gather_logits:
-                logits = jax.lax.all_gather(
-                    logits, plan.tp_axis, axis=logits.ndim - 1, tiled=True)
-            return logits
+    def local_step(p, kp, vp, bt, lengths, tokens, *scales):
+        logits = _verify_body(cfg, p, kp, vp, bt, lengths, tokens, *scales,
+                              impl=impl, axis_name=plan.tp_axis)
+        if gather_logits:
+            logits = jax.lax.all_gather(
+                logits, plan.tp_axis, axis=logits.ndim - 1, tiled=True)
+        return logits
 
-        in_specs = (specs, kv_spec, kv_spec, sc_spec, sc_spec,
-                    rep, rep, rep)
-    else:
-        def local_step(p, kp, vp, bt, lengths, tokens):
-            logits = _verify_body(cfg, p, kp, vp, bt, lengths, tokens,
-                                  impl=impl, axis_name=plan.tp_axis)
-            if gather_logits:
-                logits = jax.lax.all_gather(
-                    logits, plan.tp_axis, axis=logits.ndim - 1, tiled=True)
-            return logits
+    in_specs = (specs, kv_spec, kv_spec, rep, rep, rep) + scale_specs
 
-        in_specs = (specs, kv_spec, kv_spec, rep, rep, rep)
-
-    fn = shard_map(local_step, mesh=plan.mesh, in_specs=in_specs,
-                   out_specs=rep, check_rep=False)
+    fn = jax.shard_map(local_step, mesh=plan.mesh, in_specs=in_specs,
+                       out_specs=rep, check_vma=False)
     return jax.jit(fn)
 
 
@@ -649,23 +640,16 @@ def build_tp_prefix_step(cfg: ArchConfig, plan: ParallelPlan, params: Any,
     sc_spec = scale_spec(plan)
     rep = P()
     new_kv_spec = P(None, None, None, plan.tp_axis)
+    scale_specs = (sc_spec, sc_spec) if quantized else ()
 
-    if quantized:
-        def local_step(p, kp, vp, ks, vs, bt, lengths, tokens):
-            return _prefix_body(cfg, p, kp, vp, bt, lengths, tokens,
-                                ks, vs, impl=impl, axis_name=plan.tp_axis)
+    def local_step(p, kp, vp, bt, lengths, tokens, *scales):
+        return _prefix_body(cfg, p, kp, vp, bt, lengths, tokens, *scales,
+                            impl=impl, axis_name=plan.tp_axis)
 
-        in_specs = (specs, kv_spec, kv_spec, sc_spec, sc_spec,
-                    rep, rep, rep)
-    else:
-        def local_step(p, kp, vp, bt, lengths, tokens):
-            return _prefix_body(cfg, p, kp, vp, bt, lengths, tokens,
-                                impl=impl, axis_name=plan.tp_axis)
+    in_specs = (specs, kv_spec, kv_spec, rep, rep, rep) + scale_specs
 
-        in_specs = (specs, kv_spec, kv_spec, rep, rep, rep)
-
-    fn = shard_map(local_step, mesh=plan.mesh, in_specs=in_specs,
-                   out_specs=(new_kv_spec, new_kv_spec), check_rep=False)
+    fn = jax.shard_map(local_step, mesh=plan.mesh, in_specs=in_specs,
+                       out_specs=(new_kv_spec, new_kv_spec), check_vma=False)
     return jax.jit(fn)
 
 
@@ -853,7 +837,7 @@ class ServeEngine:
         # level routing, the kernel-level impl underneath it is "ref")
         self._chunk_impl = "ref" if impl == "fused_ref" else impl
         dt = jnp.dtype(jnp.int8) if self.quantized else jnp.dtype(cfg.dtype)
-        shape = (cfg.num_layers, num_pages, page_size, cfg.num_kv_heads,
+        shape = (cfg.num_layers, num_pages, cfg.num_kv_heads, page_size,
                  cfg.head_dim)
         # allocate the pools directly into their mesh sharding — a pool
         # sized for aggregate-mesh HBM must never transit one device
@@ -906,6 +890,9 @@ class ServeEngine:
         # kv.commit/abort/invalidate resolves both domains atomically.
         self.token_domain = TokenDomain()
         self.kv.tree.attach(self.token_domain)
+        # the last decode step's logits [b, V], left on the device: what
+        # a correctness check compares against a dense prefill
+        self.last_logits: Optional[jax.Array] = None
         # CoW fault-service instrumentation: the former ad-hoc int
         # attributes are now registry counters; the same names stay
         # readable as properties below (benchmarks/tests read those)
@@ -1025,14 +1012,14 @@ class ServeEngine:
                         jnp.round(fp / sc[:, None, :, None]),
                         -127, 127).astype(jnp.int8)
                     setattr(self, pool, getattr(self, pool).at[
-                        :, page, : hi - lo].set(q8))
+                        :, page, :, : hi - lo].set(q8.swapaxes(1, 2)))
                     setattr(self, scales, getattr(self, scales).at[
                         :, page].set(sc))
             else:
                 self.k_pages = self.k_pages.at[
-                    :, page, : hi - lo].set(k[:, lo:hi])
+                    :, page, :, : hi - lo].set(k[:, lo:hi].swapaxes(1, 2))
                 self.v_pages = self.v_pages.at[
-                    :, page, : hi - lo].set(v[:, lo:hi])
+                    :, page, :, : hi - lo].set(v[:, lo:hi].swapaxes(1, 2))
         # eager scatter of an unsharded prefill cache can drift the
         # pool's layout; re-pin so the hot loop never pays a
         # per-step reshard at the shard_map boundary
@@ -1339,6 +1326,7 @@ class ServeEngine:
             logits, self.k_pages, self.v_pages = paged_decode_step(
                 self.cfg, self.params, *step_args, impl=self.attn_impl)
         logits = logits[:, 0]
+        self.last_logits = logits
         if all(greedy_row):
             nxt = jnp.argmax(logits, axis=-1)
         else:

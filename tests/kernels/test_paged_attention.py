@@ -15,8 +15,8 @@ def make_case(key, b, kv, g, hd, page, n_pages, max_pages, dtype,
               shared_prefix=False):
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (b, kv, g, hd), dtype)
-    k_pages = jax.random.normal(ks[1], (n_pages, page, kv, hd), dtype)
-    v_pages = jax.random.normal(ks[2], (n_pages, page, kv, hd), dtype)
+    k_pages = jax.random.normal(ks[1], (n_pages, kv, page, hd), dtype)
+    v_pages = jax.random.normal(ks[2], (n_pages, kv, page, hd), dtype)
     if shared_prefix:
         # branched layout: all sequences share the first half of their
         # tables (CoW prefix), private tails (the paper's fork pattern)
@@ -77,7 +77,7 @@ def test_length_one_sequences():
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=2e-6, atol=2e-6)
     # with length 1, output == v of the single cached token
-    v0 = vp[bt[:, 0], 0]                      # [b, kv, hd]
+    v0 = vp[bt[:, 0], :, 0]                   # [b, kv, hd]
     np.testing.assert_allclose(np.asarray(out_k[:, :, 0]),
                                np.asarray(v0), rtol=2e-6, atol=2e-6)
 

@@ -30,8 +30,8 @@ def make_case(key, b, t, kv, g, hd, page, n_pages, max_pages, dtype):
     q = jax.random.normal(ks[0], (b, t, kv, g, hd), dtype)
     k_new = jax.random.normal(ks[1], (b, t, kv, hd), dtype)
     v_new = jax.random.normal(ks[2], (b, t, kv, hd), dtype)
-    k_pages = jax.random.normal(ks[3], (n_pages, page, kv, hd), dtype)
-    v_pages = jax.random.normal(ks[4], (n_pages, page, kv, hd), dtype)
+    k_pages = jax.random.normal(ks[3], (n_pages, kv, page, hd), dtype)
+    v_pages = jax.random.normal(ks[4], (n_pages, kv, page, hd), dtype)
     bt = jax.random.randint(ks[5], (b, max_pages), 0, n_pages,
                             dtype=jnp.int32)
     lengths = jax.random.randint(ks[6], (b,), 0, max_pages * page + 1,
@@ -74,8 +74,8 @@ def test_ref_matches_dense_attention():
     for bi in range(b):
         ln = int(lengths[bi])
         # the real cached keys, in table order, truncated to length
-        kc = kp[bt[bi]].reshape(-1, kv, hd)[:ln]
-        vc = vp[bt[bi]].reshape(-1, kv, hd)[:ln]
+        kc = jnp.swapaxes(kp[bt[bi]], 1, 2).reshape(-1, kv, hd)[:ln]
+        vc = jnp.swapaxes(vp[bt[bi]], 1, 2).reshape(-1, kv, hd)[:ln]
         for ti in range(t):
             keys = jnp.concatenate([kc, kn[bi, : ti + 1]], axis=0)
             vals = jnp.concatenate([vc, vn[bi, : ti + 1]], axis=0)
@@ -118,14 +118,14 @@ def test_int8_pages_dequant_in_kernel(impl):
     q, kn, vn, kp, vp, bt, lengths, pm = make_case(
         jax.random.PRNGKey(5), b, t, kv, g, hd, page, n_pages, max_pages,
         jnp.float32)
-    ks = jnp.max(jnp.abs(kp), axis=(1, 3)) / 127.0 + 1e-8  # [n_pages, kv]
-    vs = jnp.max(jnp.abs(vp), axis=(1, 3)) / 127.0 + 1e-8
-    kq = jnp.round(kp / ks[:, None, :, None]).astype(jnp.int8)
-    vq = jnp.round(vp / vs[:, None, :, None]).astype(jnp.int8)
+    ks = jnp.max(jnp.abs(kp), axis=(2, 3)) / 127.0 + 1e-8  # [n_pages, kv]
+    vs = jnp.max(jnp.abs(vp), axis=(2, 3)) / 127.0 + 1e-8
+    kq = jnp.round(kp / ks[:, :, None, None]).astype(jnp.int8)
+    vq = jnp.round(vp / vs[:, :, None, None]).astype(jnp.int8)
     out_q = paged_chunk_attention(q, kn, vn, kq, vq, bt, lengths, pm,
                                   ks, vs, impl=impl)
-    kd = kq.astype(jnp.float32) * ks[:, None, :, None]
-    vd = vq.astype(jnp.float32) * vs[:, None, :, None]
+    kd = kq.astype(jnp.float32) * ks[:, :, None, None]
+    vd = vq.astype(jnp.float32) * vs[:, :, None, None]
     out_d = paged_chunk_attention(q, kn, vn, kd, vd, bt, lengths, pm,
                                   impl=impl)
     np.testing.assert_allclose(np.asarray(out_q), np.asarray(out_d),
@@ -138,13 +138,12 @@ def test_t1_equals_legacy_decode_path():
     q, kn, vn, kp, vp, _, lengths, pm = make_case(
         jax.random.PRNGKey(6), b, 1, kv, g, hd, page, n_pages, max_pages,
         jnp.float32)
-    # real block tables never repeat a page within a row — and the
-    # legacy materialized write would otherwise be visible at every
-    # duplicate table position at once
-    bt = jnp.stack([
-        jax.random.permutation(jax.random.PRNGKey(10 + i),
-                               n_pages)[:max_pages]
-        for i in range(b)]).astype(jnp.int32)
+    # real block tables never repeat a page, within a row or across
+    # live rows — the legacy materialized write would otherwise be
+    # visible at every duplicate table position at once (one row's
+    # token write landing in a page another row reads)
+    bt = jax.random.permutation(jax.random.PRNGKey(10), n_pages)[
+        :b * max_pages].reshape(b, max_pages).astype(jnp.int32)
     # lengths must leave room in the table for the appended token
     lengths = lengths % (max_pages * page - 1)
     fused = paged_chunk_attention(q, kn, vn, kp, vp, bt, lengths, pm,
@@ -152,8 +151,8 @@ def test_t1_equals_legacy_decode_path():
     # legacy: write the token into its slot, then cached-only attention
     slot = lengths // page
     off = lengths % page
-    kp2 = kp.at[bt[jnp.arange(b), slot], off].set(kn[:, 0])
-    vp2 = vp.at[bt[jnp.arange(b), slot], off].set(vn[:, 0])
+    kp2 = kp.at[bt[jnp.arange(b), slot], :, off].set(kn[:, 0])
+    vp2 = vp.at[bt[jnp.arange(b), slot], :, off].set(vn[:, 0])
     legacy = paged_attention(q[:, 0], kp2, vp2, bt, lengths + 1,
                              impl="ref")
     np.testing.assert_allclose(np.asarray(fused[:, 0]),

@@ -40,7 +40,7 @@ def test_sharded_train_step_runs_on_8_devices():
     run_in_subprocess("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config, reduced
-        from repro.distributed.mesh import plan_from_mesh
+        from repro.distributed.mesh import make_mesh, plan_from_mesh
         from repro.distributed.sharding import (batch_shardings,
             param_shardings, shard_params)
         from repro.models.model import Model
@@ -50,7 +50,7 @@ def test_sharded_train_step_runs_on_8_devices():
 
         cfg = dataclasses.replace(reduced(get_config("granite-8b"),
             d_model=128), dtype="float32")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         plan = plan_from_mesh(mesh)
         model = Model(cfg, plan=plan, attn_chunk=8, loss_chunk=8,
                       remat=False)
@@ -141,21 +141,19 @@ def test_ring_allreduce_and_quantized_psum():
         mesh = jax.make_mesh((8,), ("pod",))
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 
-        from repro.distributed import shard_map
-
-        ring = jax.jit(shard_map(
+        ring = jax.jit(jax.shard_map(
             lambda v: ring_allreduce(v, "pod", 8), mesh=mesh,
             in_specs=P("pod", None), out_specs=P("pod", None),
-            check_rep=False))
+            check_vma=False))
         got = ring(x)
         want = jnp.tile(x.sum(0, keepdims=True), (8, 1))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-6)
 
-        qsum = jax.jit(shard_map(
+        qsum = jax.jit(jax.shard_map(
             lambda v: psum_quantized(v, "pod"), mesh=mesh,
             in_specs=P("pod", None), out_specs=P("pod", None),
-            check_rep=False))
+            check_vma=False))
         got_q = qsum(x)
         # int8 quantization: bounded relative error vs exact psum
         err = np.abs(np.asarray(got_q) - np.asarray(want))

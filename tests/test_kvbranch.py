@@ -336,3 +336,22 @@ def test_random_op_interleavings_preserve_invariants():
         except (BranchError, MemoryError, ValueError):
             pass  # rejected ops must leave state consistent too
         _check_refcount_invariants(kv)
+
+
+def test_clear_prefix_cache_returns_the_pool():
+    """Released sequences plus a cleared prefix cache leave every page
+    free; a live sharer keeps its pages through the clear."""
+    kv = KVBranchManager(num_pages=16, page_size=4)
+    prompt = list(range(1, 11))                 # 2 full pages + a tail
+    a = kv.new_seq(length=len(prompt))
+    assert kv.register_prefix(a, prompt) == 3
+    pages, covered = kv.match_prefix(prompt)
+    b = kv.new_seq(length=covered, prefix_pages=pages)
+    kv.release(a)
+    assert kv.clear_prefix_cache() == 3
+    assert kv.match_prefix(prompt) == ([], 0)
+    assert kv.free_pages == 16 - len(kv.block_table(b))
+    _check_refcount_invariants(kv)
+    kv.release(b)
+    assert kv.free_pages == 16
+    _check_refcount_invariants(kv)
