@@ -171,16 +171,6 @@ def main(argv=None) -> None:
                         "value": round(time.time() - t0, 3),
                         "derived": "harness"})
 
-    # the obs-registry view across every engine/manager the benchmark
-    # modules created (live + already-GC'd hubs), so the trajectory
-    # carries dispatch counts and latency percentiles, not just
-    # wall-clock rows
-    try:
-        from repro.obs import merged_snapshot
-        metrics = merged_snapshot()
-    except Exception:
-        metrics = {}
-
     stamp = time.strftime("%Y%m%d_%H%M%S")
     out = Path(args.out) if args.out else Path(f"BENCH_{stamp}.json")
     out.write_text(json.dumps({
@@ -191,7 +181,6 @@ def main(argv=None) -> None:
         "platform": platform.platform(),
         "failed": failed,
         "rows": records,
-        "metrics": metrics,
     }, indent=2))
     print(f"wrote {out}")
     misses = check_derived(records)

@@ -6,6 +6,9 @@ raises ``TypeError`` at runtime, *in whoever asks second*, which may be
 a benchmark harness three modules away from the collision.  This rule
 moves the whole contract to lint time:
 
+* **Spans** — a literal ``span("a.b")`` (``Observability.span``)
+  registers the histogram ``a.b_us``, so its name is held to the same
+  grammar and joins the same kind table.
 * **Grammar** — literal instrument names must be dotted
   ``component.metric`` (``sched.submitted``, ``kv.cow_faults``):
   lowercase, at least one dot, no uppercase/dashes/leading digits.
@@ -32,8 +35,12 @@ from repro.analysis.engine import (FileContext, Finding, Project, Rule,
                                    register)
 from repro.analysis.rules.common import calls_in
 
-#: registration verbs -> instrument kind
-_KINDS = {"counter": "counter", "gauge": "gauge", "histogram": "histogram"}
+#: registration verbs -> instrument kind (a timed span is a histogram)
+_KINDS = {"counter": "counter", "gauge": "gauge", "histogram": "histogram",
+          "span": "histogram"}
+
+#: what a verb appends to its literal name to make the instrument's name
+_SUFFIX = {"span": "_us"}
 
 #: component.metric grammar (underscored lowercase segments, >=1 dot)
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+$")
@@ -47,18 +54,28 @@ def _literal_name(call: ast.Call) -> str:
     return ""
 
 
-def _kind_of(call: ast.Call) -> str:
+def _verb(call: ast.Call) -> str:
     func = call.func
-    name = func.attr if isinstance(func, ast.Attribute) else \
+    return func.attr if isinstance(func, ast.Attribute) else \
         func.id if isinstance(func, ast.Name) else ""
-    return _KINDS.get(name, "")
+
+
+def _kind_of(call: ast.Call) -> str:
+    return _KINDS.get(_verb(call), "")
+
+
+def _instrument_name(call: ast.Call) -> str:
+    """The registered instrument's literal name (``span("a.b")``
+    registers ``a.b_us``), or ''."""
+    name = _literal_name(call)
+    return name + _SUFFIX.get(_verb(call), "") if name else ""
 
 
 def _registrations(ctx: FileContext) -> List[Tuple[str, str, int]]:
     """Every literal-name (name, kind, line) registration in a file."""
     out = []
     for call in calls_in(ctx.tree, *_KINDS):
-        name = _literal_name(call)
+        name = _instrument_name(call)
         if name:
             out.append((name, _kind_of(call), getattr(call, "lineno", 0)))
     return out
@@ -76,7 +93,7 @@ class MetricHygiene(Rule):
     def visit(self, ctx: FileContext) -> List[Finding]:
         out: List[Finding] = []
         for call in calls_in(ctx.tree, *_KINDS):
-            name = _literal_name(call)
+            name = _instrument_name(call)
             if not name:
                 continue        # dynamic name: grammar not checkable
             if not _NAME_RE.match(name):

@@ -372,28 +372,33 @@ class BranchSession:
     def _fork_domains(
         self, entry: _Entry, n: int, flags: int
     ) -> List[Tuple[int, Optional[StateContext], Optional[BranchHandle]]]:
-        """One atomic multi-domain fork attempt (raises AdmissionDenied)."""
-        if entry.seq is None and not self._try_bind(entry):
-            # still in the admission FIFO: backpressure, not an error —
-            # the blocking path keeps stepping until admission happens
-            raise AdmissionDenied(
-                f"handle {entry.hd:#x} is not admitted yet (-EAGAIN)")
-        if self.runtime is not None and entry.state is not None:
-            # check the cheap reservation ledger BEFORE forking the
-            # store domain: a backpressure retry loop must not churn
-            # (fork + unwind) store nodes every round
-            if not self.sched.can_fork(entry.seq, n):
+        """One atomic multi-domain fork attempt (raises AdmissionDenied);
+        a successful one is timed as ``session.fork``."""
+        with self.obs.span("session.fork", req=entry.req_id, n=n):
+            if entry.seq is None and not self._try_bind(entry):
+                # still in the admission FIFO: backpressure, not an
+                # error — the blocking path keeps stepping until
+                # admission happens
                 raise AdmissionDenied(
-                    f"branch({entry.seq}, n={n}) exceeds the page budget "
-                    "(-EAGAIN)")
-            rt_flags = BR_STATE | BR_KV
-            if flags & BR_ISOLATE:
-                rt_flags |= RT_ISOLATE
-            handles = self.runtime.create(entry.state, n, flags=rt_flags,
-                                          kv_seqs=[entry.seq])
-            return [(h.kv_seqs[entry.seq], h.state, h) for h in handles]
-        seqs = self.sched.fork(entry.seq, n, eager_cow=True)
-        return [(s, None, None) for s in seqs]
+                    f"handle {entry.hd:#x} is not admitted yet (-EAGAIN)")
+            if self.runtime is not None and entry.state is not None:
+                # check the cheap reservation ledger BEFORE forking the
+                # store domain: a backpressure retry loop must not churn
+                # (fork + unwind) store nodes every round
+                if not self.sched.can_fork(entry.seq, n):
+                    raise AdmissionDenied(
+                        f"branch({entry.seq}, n={n}) exceeds the page "
+                        "budget (-EAGAIN)")
+                rt_flags = BR_STATE | BR_KV
+                if flags & BR_ISOLATE:
+                    rt_flags |= RT_ISOLATE
+                handles = self.runtime.create(entry.state, n,
+                                              flags=rt_flags,
+                                              kv_seqs=[entry.seq])
+                return [(h.kv_seqs[entry.seq], h.state, h)
+                        for h in handles]
+            seqs = self.sched.fork(entry.seq, n, eager_cow=True)
+            return [(s, None, None) for s in seqs]
 
     def _fork_blocking(self, entry: _Entry, n: int, flags: int,
                        max_steps: int) -> List[Tuple[int, Any, Any]]:
@@ -440,27 +445,28 @@ class BranchSession:
         if entry.parent_hd is None:
             raise BranchStateError(
                 "root branch cannot commit; finish() retires a request")
-        try:
-            if entry.rt_handle is not None:
-                self.runtime.commit(entry.rt_handle)
-            else:
-                self.engine.commit(entry.seq)
-        except StaleBranchError:
-            entry.resolved = "stale"
-            entry.events |= EV_INVALIDATED
-            raise
-        entry.resolved = "committed"
-        entry.events |= EV_COMMITTED
-        for sib_hd in entry.group:
-            if sib_hd == hd:
-                continue
+        with self.obs.span("session.commit", req=entry.req_id):
             try:
-                sib = self._entry(sib_hd)
-            except BadHandleError:
-                continue
-            if sib.resolved is None:
-                sib.resolved = "stale"
-                sib.events |= EV_INVALIDATED
+                if entry.rt_handle is not None:
+                    self.runtime.commit(entry.rt_handle)
+                else:
+                    self.engine.commit(entry.seq)
+            except StaleBranchError:
+                entry.resolved = "stale"
+                entry.events |= EV_INVALIDATED
+                raise
+            entry.resolved = "committed"
+            entry.events |= EV_COMMITTED
+            for sib_hd in entry.group:
+                if sib_hd == hd:
+                    continue
+                try:
+                    sib = self._entry(sib_hd)
+                except BadHandleError:
+                    continue
+                if sib.resolved is None:
+                    sib.resolved = "stale"
+                    sib.events |= EV_INVALIDATED
         return entry.parent_hd
 
     def abort(self, hd: int) -> None:

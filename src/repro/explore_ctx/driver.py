@@ -408,36 +408,40 @@ class ExplorationDriver:
             exp.final_tokens = self.session.finish(exp.hd)
 
     def step(self, **decode_kw: Any) -> Dict[str, Any]:
-        """One round: resume ready explorations, then one session step."""
-        self.session.admit()   # admit first so _WaitAdmitted binds + holds
-        resumed = 0
-        for exp in list(self._live):
-            if exp.done:
-                continue
-            if exp.wait is None:
-                self._advance(exp)
-                resumed += 1
-            else:
-                try:
-                    ok, value = exp.wait.poll(self)
-                except Exception as err:
-                    # a wait that can never be satisfied (its context was
-                    # evicted/resolved underneath it) fails into the
-                    # policy, not the driver loop
-                    exp.wait = None
-                    self._advance(exp, error=err)
-                    resumed += 1
+        """One round: resume ready explorations, then one session step
+        (timed as ``driver.step``)."""
+        with self.session.obs.span("driver.step"):
+            # admit first so _WaitAdmitted binds + holds
+            self.session.admit()
+            resumed = 0
+            for exp in list(self._live):
+                if exp.done:
                     continue
-                if ok:
-                    exp.wait = None
-                    if isinstance(value, BranchContext) and exp.root is None:
-                        exp.root = value
-                    self._advance(exp, value)
+                if exp.wait is None:
+                    self._advance(exp)
                     resumed += 1
-        st = self.session.step(**decode_kw)
-        st["resumed"] = resumed
-        st["live_explorations"] = len(self._live)
-        self.steps += 1
+                else:
+                    try:
+                        ok, value = exp.wait.poll(self)
+                    except Exception as err:
+                        # a wait that can never be satisfied (its context
+                        # was evicted/resolved underneath it) fails into
+                        # the policy, not the driver loop
+                        exp.wait = None
+                        self._advance(exp, error=err)
+                        resumed += 1
+                        continue
+                    if ok:
+                        exp.wait = None
+                        if isinstance(value, BranchContext) \
+                                and exp.root is None:
+                            exp.root = value
+                        self._advance(exp, value)
+                        resumed += 1
+            st = self.session.step(**decode_kw)
+            st["resumed"] = resumed
+            st["live_explorations"] = len(self._live)
+            self.steps += 1
         return st
 
     def run(self, max_steps: int = 10_000, *,

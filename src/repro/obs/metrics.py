@@ -4,8 +4,8 @@ Zero-dependency and process-local.  Every instrument is get-or-create
 by name through one :class:`Metrics` registry; a name is bound to
 exactly one kind (asking for ``counter("x")`` after ``gauge("x")`` is a
 programming error and raises).  The registry is what
-``ServeEngine.stats()`` / ``BranchSession.stat(metrics=True)`` /
-``benchmarks/run.py`` snapshot, and what the ad-hoc serving counters
+``ServeEngine.stats()`` / ``BranchSession.stat(metrics=True)`` and
+the chip benchmark snapshot, and what the ad-hoc serving counters
 (``cow_dispatches`` et al.) became views over.
 
 Design points
@@ -22,7 +22,7 @@ Design points
   estimate), which is exact enough for p50/p90/p99 trend lines and
   costs O(n_buckets) only at snapshot time; ``observe`` is one bisect
   plus four scalar updates.
-* ``snapshot()`` returns plain dicts (JSON-ready for BENCH_*.json);
+* ``snapshot()`` returns plain, JSON-ready dicts;
   ``format()`` returns the procfs-style text block used by
   ``session.format_tree(metrics=True)`` and the ``--trace`` demos.
 """
@@ -173,43 +173,10 @@ class Metrics:
             lambda: Histogram(name, lo=lo, growth=growth, buckets=buckets))
 
     # ------------------------------------------------------------------
-    # merge / export
+    # export
     # ------------------------------------------------------------------
-    def absorb(self, other: "Metrics") -> None:
-        """Fold another registry into this one (cross-hub aggregation).
-
-        Counters and histograms are additive; gauges take the other's
-        value (last-writer-wins — per-pool levels do not sum
-        meaningfully across engines, so ``merged_snapshot`` documents
-        gauges as per-hub latest).
-        """
-        with other._lock:
-            counters = list(other._counters.values())
-            gauges = list(other._gauges.values())
-            histograms = list(other._histograms.values())
-        for c in counters:
-            self.counter(c.name).inc(c.value)
-        for g in gauges:
-            self.gauge(g.name).set(g.value)
-        for h in histograms:
-            mine = self.histogram(h.name)
-            if mine.bounds != h.bounds:      # geometry mismatch: refit
-                for i, c in enumerate(h.counts):
-                    if c:
-                        v = h.bounds[i] if i < len(h.bounds) else h.max
-                        for _ in range(c):
-                            mine.observe(v)
-                continue
-            for i, c in enumerate(h.counts):
-                mine.counts[i] += c
-            mine.count += h.count
-            mine.sum += h.sum
-            if h.count:
-                mine.min = min(mine.min, h.min)
-                mine.max = max(mine.max, h.max)
-
     def snapshot(self) -> dict:
-        """JSON-ready dict: the metrics block of ``BENCH_*.json``."""
+        """JSON-ready dict of every instrument's current value."""
         with self._lock:
             return {
                 "counters": {n: c.value

@@ -66,6 +66,7 @@ class Request:
     seq: Optional[int] = None          # assigned at admission
     hold_on_admit: bool = False        # park immediately (explorations)
     submitted_ns: int = 0              # queue-wait clock start
+    first_token: bool = False          # a sequence it owns has decoded
 
 
 class Scheduler:
@@ -118,6 +119,7 @@ class Scheduler:
         self._c_demotions = m.counter("sched.demotions")
         self._c_restores = m.counter("sched.restores")
         self._h_admission_wait = m.histogram("sched.admission_wait_us")
+        self._h_first_token = m.histogram("sched.first_token_us")
         self._g_reserved = m.gauge("sched.pages_reserved")
 
     @property
@@ -211,6 +213,8 @@ class Scheduler:
             if req.worst_pages > budget:
                 break   # FIFO: do not starve the head request
             self._waiting.pop(0)
+            self._h_admission_wait.observe(
+                (time.perf_counter_ns() - req.submitted_ns) / 1000.0)
             req.seq = self.engine.add_request(req.prompt)
             self._seq_owner[req.seq] = req.req_id
             self._reserved[req.seq] = req.worst_pages
@@ -218,8 +222,6 @@ class Scheduler:
                 self._holds.add(req.seq)
             admitted.append(req.req_id)
             self._c_admitted.inc()
-            self._h_admission_wait.observe(
-                (time.perf_counter_ns() - req.submitted_ns) / 1000.0)
         if admitted:
             self._g_reserved.set(self._pages_reserved())
         return admitted
@@ -534,6 +536,7 @@ class Scheduler:
                     self._key, sub = jax.random.split(self._key)
             self.engine.decode(group, greedy=g_row,
                                temperature=t_row, key=sub)
+            self._note_first_tokens(group)
             decoded += len(group)
         retired = 0
         for seq in self.runnable():   # re-asks the kernel; purges resolved
@@ -553,6 +556,18 @@ class Scheduler:
             "waiting": len(self._waiting),
             "running": len(self._seq_owner),
         }
+
+    def _note_first_tokens(self, group: Sequence[int]) -> None:
+        """Time submit -> first token for each request whose first token
+        this decode produced (forked children carry their owner, so an
+        exploration's first sampled token counts for its request)."""
+        now = time.perf_counter_ns()
+        for s in group:
+            req = self._requests.get(self._seq_owner[s])
+            if req is not None and not req.first_token:
+                req.first_token = True
+                self._h_first_token.observe(
+                    (now - req.submitted_ns) / 1000.0)
 
     def seed_sampling(self, key: jax.Array) -> None:
         """Reseed the scheduler-owned PRNG stream for sampled decode."""

@@ -904,12 +904,11 @@ class ServeEngine:
         self._c_decode_steps = m.counter("engine.decode_steps")
         self._c_tokens = m.counter("engine.tokens_decoded")
         self._c_prefill_dispatches = m.counter("engine.prefill_dispatches")
+        # prompt tokens prefilled or adopted (all but each prompt's
+        # pending last token), and those the prefix cache served
+        self._c_prompt_tokens = m.counter("engine.prompt_tokens")
+        self._c_prompt_cached = m.counter("engine.prompt_tokens_cached")
         self._h_fork_us = m.histogram("engine.fork_us")
-        self._h_commit_us = m.histogram("engine.commit_us")
-        self._h_prefill_us = m.histogram("engine.prefill_us")
-        self._h_checkpoint_us = m.histogram("tier.checkpoint_us")
-        self._h_restore_us = m.histogram("tier.restore_us")
-        self._h_decode_us = m.histogram("engine.decode_step_us")
         self._h_batch = m.histogram("engine.batch_occupancy",
                                     lo=1.0, growth=2.0, buckets=12)
         pool_bytes = int(self.k_pages.nbytes + self.v_pages.nbytes)
@@ -1075,23 +1074,24 @@ class ServeEngine:
         """
         prompt = list(prompt)
         assert prompt, "empty prompt"
-        t0 = time.perf_counter_ns()
-        n_cached = len(prompt) - 1
-        shared: List[int] = []
-        covered = 0
-        if self.prefix_cache and n_cached:
-            shared, covered = self.kv.match_prefix(prompt[:-1])
-        sid = self.kv.new_seq(length=n_cached,
-                              prefix_pages=shared or None)
-        if n_cached > covered:
-            if covered:
-                self._chunk_prefill(sid, prompt[:-1], covered)
-            else:
-                self._dense_prefill(sid, prompt[:-1])
-        if self.prefix_cache and n_cached:
-            self.kv.register_prefix(sid, prompt[:-1])
-        self.token_domain.seed(sid, prompt)
-        self._h_prefill_us.observe((time.perf_counter_ns() - t0) / 1000.0)
+        with self.obs.span("engine.prefill", tokens=len(prompt)):
+            n_cached = len(prompt) - 1
+            shared: List[int] = []
+            covered = 0
+            if self.prefix_cache and n_cached:
+                shared, covered = self.kv.match_prefix(prompt[:-1])
+            sid = self.kv.new_seq(length=n_cached,
+                                  prefix_pages=shared or None)
+            if n_cached > covered:
+                if covered:
+                    self._chunk_prefill(sid, prompt[:-1], covered)
+                else:
+                    self._dense_prefill(sid, prompt[:-1])
+            if self.prefix_cache and n_cached:
+                self.kv.register_prefix(sid, prompt[:-1])
+            self.token_domain.seed(sid, prompt)
+            self._c_prompt_tokens.inc(n_cached)
+            self._c_prompt_cached.inc(covered)
         return sid
 
     # ------------------------------------------------------------------
@@ -1121,10 +1121,9 @@ class ServeEngine:
         return children
 
     def commit(self, seq: int) -> int:
-        t0 = time.perf_counter_ns()
-        parent = self.kv.commit(seq)  # tokens + pages promoted atomically
-        self._h_commit_us.observe((time.perf_counter_ns() - t0) / 1000.0)
-        return parent
+        with self.obs.span("engine.commit"):
+            # tokens + pages promoted atomically
+            return self.kv.commit(seq)
 
     def abort(self, seq: int) -> None:
         self.kv.abort(seq)
@@ -1160,26 +1159,25 @@ class ServeEngine:
         decode until restored.  Returns the number of device pages
         freed.
         """
-        t0 = time.perf_counter_ns()
-        table = self.kv.block_table(seq)      # raises ENOENT if unknown
-        length = self.kv.length(seq)
-        tokens = list(self.token_domain.get(seq))
-        idx = jnp.asarray(table, jnp.int32)
-        snap = KVSnapshot(
-            seq_id=seq, length=length, n_pages=len(table), tokens=tokens,
-            k_pages=np.asarray(self.k_pages[:, idx]),
-            v_pages=np.asarray(self.v_pages[:, idx]),
-            k_scales=(np.asarray(self.k_scales[:, idx])
-                      if self.quantized else None),
-            v_scales=(np.asarray(self.v_scales[:, idx])
-                      if self.quantized else None))
-        # demote AFTER the gather: it validates (live, leaf, not already
-        # tiered) and raises with the snapshot discarded and the device
-        # state untouched
-        self.kv.demote(seq)
-        self.tier.put(snap)
-        self._h_checkpoint_us.observe(
-            (time.perf_counter_ns() - t0) / 1000.0)
+        with self.obs.span("tier.checkpoint"):
+            table = self.kv.block_table(seq)  # raises ENOENT if unknown
+            length = self.kv.length(seq)
+            tokens = list(self.token_domain.get(seq))
+            idx = jnp.asarray(table, jnp.int32)
+            snap = KVSnapshot(
+                seq_id=seq, length=length, n_pages=len(table),
+                tokens=tokens,
+                k_pages=np.asarray(self.k_pages[:, idx]),
+                v_pages=np.asarray(self.v_pages[:, idx]),
+                k_scales=(np.asarray(self.k_scales[:, idx])
+                          if self.quantized else None),
+                v_scales=(np.asarray(self.v_scales[:, idx])
+                          if self.quantized else None))
+            # demote AFTER the gather: it validates (live, leaf, not
+            # already tiered) and raises with the snapshot discarded and
+            # the device state untouched
+            self.kv.demote(seq)
+            self.tier.put(snap)
         return len(table)
 
     def restore(self, seq: int) -> None:
@@ -1189,24 +1187,23 @@ class ServeEngine:
         the pool cannot fit it (``PoolExhausted``) — the caller demotes
         something else and retries (the scheduler's demote-before-deny).
         """
-        t0 = time.perf_counter_ns()
-        snap = self.tier.get(seq)             # ENOENT if never tiered
-        pages = self.kv.promote(seq)          # ENOSPC leaves snap stored
-        if pages:
-            idx = jnp.asarray(pages, jnp.int32)
-            self.k_pages = self._pin_kv(
-                self.k_pages.at[:, idx].set(jnp.asarray(snap.k_pages)))
-            self.v_pages = self._pin_kv(
-                self.v_pages.at[:, idx].set(jnp.asarray(snap.v_pages)))
-            if self.quantized and snap.k_scales is not None:
-                self.k_scales = self.k_scales.at[:, idx].set(
-                    jnp.asarray(snap.k_scales))
-                self.v_scales = self.v_scales.at[:, idx].set(
-                    jnp.asarray(snap.v_scales))
-                self._pin_scales()
-        self.token_domain.seed(seq, snap.tokens)
-        self.tier.drop(seq)
-        self._h_restore_us.observe((time.perf_counter_ns() - t0) / 1000.0)
+        with self.obs.span("tier.restore"):
+            snap = self.tier.get(seq)         # ENOENT if never tiered
+            pages = self.kv.promote(seq)      # ENOSPC leaves snap stored
+            if pages:
+                idx = jnp.asarray(pages, jnp.int32)
+                self.k_pages = self._pin_kv(
+                    self.k_pages.at[:, idx].set(jnp.asarray(snap.k_pages)))
+                self.v_pages = self._pin_kv(
+                    self.v_pages.at[:, idx].set(jnp.asarray(snap.v_pages)))
+                if self.quantized and snap.k_scales is not None:
+                    self.k_scales = self.k_scales.at[:, idx].set(
+                        jnp.asarray(snap.k_scales))
+                    self.v_scales = self.v_scales.at[:, idx].set(
+                        jnp.asarray(snap.v_scales))
+                    self._pin_scales()
+            self.token_domain.seed(seq, snap.tokens)
+            self.tier.drop(seq)
 
     def is_tiered(self, seq: int) -> bool:
         return self.kv.is_tiered(seq)
@@ -1247,9 +1244,39 @@ class ServeEngine:
         verification branches with sampled exploration branches at
         different temperatures — the exploration driver multiplexes many
         policies' decode work into a single device dispatch.
+
+        Timed as ``engine.decode_step`` in three phases: ``decode_prep``
+        (host preparation and the dispatch of the step and its sampling
+        ops), ``decode_wait`` (the host blocked on the device's result)
+        and ``decode_post`` (token append and bookkeeping).
         """
         b = len(seq_ids)
-        t0 = time.perf_counter_ns()
+        span = self.obs.span
+        with span("engine.decode_step", rows=b) as step:
+            with span("engine.decode_prep", rows=b):
+                nxt = self._decode_dispatch(seq_ids, greedy, temperature,
+                                            key)
+            with span("engine.decode_wait", rows=b):
+                host_nxt = np.asarray(nxt)
+            with span("engine.decode_post", rows=b):
+                out = [int(t) for t in host_nxt]
+                for s, t in zip(seq_ids, out):
+                    self.token_domain.append(s, t)
+                self._h_batch.observe(b)
+                self._c_decode_steps.inc()
+                self._c_tokens.inc(b)
+        tr = self.obs.tracer
+        if tr.enabled:
+            tr.instant(ENGINE_TRACK, "decode_step", batch=b,
+                       us=round(step.us, 1))
+        return out
+
+    def _decode_dispatch(self, seq_ids: Sequence[int], greedy: Any,
+                         temperature: Any,
+                         key: Optional[jax.Array]) -> jax.Array:
+        """Reserve the batch's slots and enqueue the decode step and its
+        sampling; returns the next tokens, still on the device."""
+        b = len(seq_ids)
         # resolve sampling rows BEFORE any metadata mutates: a mis-sized
         # per-sequence list must fail cleanly, not after slots were
         # reserved and the device step ran
@@ -1335,21 +1362,7 @@ class ServeEngine:
             sampled = jax.random.categorical(key, logits / temps[:, None])
             nxt = jnp.where(jnp.asarray(greedy_row),
                             jnp.argmax(logits, axis=-1), sampled)
-        out = [int(t) for t in np.asarray(nxt)]
-        for s, t in zip(seq_ids, out):
-            self.token_domain.append(s, t)
-        # np.asarray above synced the device step, so this wall time
-        # covers host bookkeeping + the dispatch it timed
-        dt_us = (time.perf_counter_ns() - t0) / 1000.0
-        self._h_decode_us.observe(dt_us)
-        self._h_batch.observe(b)
-        self._c_decode_steps.inc()
-        self._c_tokens.inc(b)
-        tr = self.obs.tracer
-        if tr.enabled:
-            tr.instant(ENGINE_TRACK, "decode_step", batch=b,
-                       us=round(dt_us, 1))
-        return out
+        return nxt
 
     def spec_verify(self, seq: int,
                     drafts: Sequence[Sequence[int]]) -> List[List[int]]:

@@ -211,6 +211,28 @@ def test_rule_passes_golden_conforming(tmp_path, code):
         f"{code} false positives: {[f.message for f in result.findings]}"
 
 
+def test_bl005_reads_a_span_as_the_histogram_it_registers(tmp_path):
+    result = check(tmp_path, """
+        def setup(obs, m):
+            m.counter("engine.decode_step_us").inc()
+            with obs.span("engine.decode_step", rows=4):
+                pass
+            with obs.span("Undotted"):
+                pass
+            with obs.span("engine.decode_prep"):
+                pass
+            m.histogram("engine.decode_prep_us").observe(1.0)
+    """, rules=["BL005"])
+    msgs = [f.message for f in result.findings]
+    assert len(msgs) == 2, msgs
+    # the span's histogram collides with a counter of the same name
+    clash = [m for m in msgs if "'engine.decode_step_us'" in m]
+    assert len(clash) == 1
+    assert "histogram" in clash[0] and "counter" in clash[0]
+    # and its name is held to the dotted grammar
+    assert any("'Undotted_us'" in m and "grammar" in m for m in msgs)
+
+
 def test_all_six_rules_registered():
     assert sorted(RULES) == [f"BL00{i}" for i in range(1, 7)]
     for code, rule in RULES.items():

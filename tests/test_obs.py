@@ -1,4 +1,5 @@
-"""Observability subsystem: metrics math, span lifecycle, Chrome export.
+"""Observability subsystem: metrics math, span lifecycle, Chrome export,
+timed spans on the profiler's clock, and the scheduler's request times.
 
 Covers the obs tentpole end to end: histogram bucket math, the
 disabled-mode no-op guarantee (call-count probe on the clock), span
@@ -11,7 +12,10 @@ matches ``BranchTree.snapshot()`` lineage.
 """
 
 import dataclasses
+import glob
 import json
+import os
+import time
 
 import jax
 import pytest
@@ -19,7 +23,7 @@ import pytest
 from repro.configs import get_config
 from repro.core.lifecycle import BranchStatus, BranchTree
 from repro.models.model import Model
-from repro.obs import Observability, merged_snapshot
+from repro.obs import Observability
 from repro.obs.metrics import Histogram, Metrics
 from repro.obs.tracer import ENGINE_TRACK, Tracer
 from repro.runtime.serve_loop import ServeEngine
@@ -71,24 +75,6 @@ def test_histogram_percentiles():
     empty = Histogram("e")
     assert empty.percentile(50) == 0.0
     assert empty.snapshot()["min"] == 0.0
-
-
-def test_metrics_absorb_and_merged_snapshot():
-    a = Observability()
-    b = Observability()
-    a.metrics.counter("t.n").inc(2)
-    b.metrics.counter("t.n").inc(3)
-    a.metrics.histogram("t.h").observe(5)
-    b.metrics.histogram("t.h").observe(7)
-    merged = Metrics()
-    merged.absorb(a.metrics)
-    merged.absorb(b.metrics)
-    assert merged.counter("t.n").value == 5
-    assert merged.histogram("t.h").count == 2
-    assert merged.histogram("t.h").sum == 12
-    # the process-wide view sees both live hubs
-    snap = merged_snapshot()
-    assert snap["counters"]["t.n"] >= 5
 
 
 def test_metrics_format_procfs_lines():
@@ -370,3 +356,168 @@ def test_untraced_engine_records_nothing(engine_setup):
     eng.fork(root, 2)
     assert eng.obs.tracer.spans == []
     assert eng.obs.tracer.instants == []
+
+
+# ---------------------------------------------------------------------------
+# timed spans: a registry histogram plus a profiler annotation
+# ---------------------------------------------------------------------------
+
+def test_span_observes_once_on_normal_exit():
+    obs = Observability()
+    with obs.span("t.phase", req=3, rows=2) as sp:
+        pass
+    h = obs.metrics.snapshot()["histograms"]["t.phase_us"]
+    assert h["count"] == 1
+    assert sp.us is not None and sp.us >= 0
+    assert h["sum"] == pytest.approx(sp.us, abs=1e-3)
+
+
+def test_span_observes_nothing_when_the_body_raises():
+    obs = Observability()
+    with obs.span("t.phase"):
+        pass
+    with pytest.raises(KeyError, match="boom"):
+        with obs.span("t.phase") as sp:
+            raise KeyError("boom")
+    assert sp.us is None
+    assert obs.metrics.histogram("t.phase_us").count == 1
+    # the annotation closed: a later span still nests and observes
+    with obs.span("t.phase"):
+        pass
+    assert obs.metrics.histogram("t.phase_us").count == 2
+
+
+def host_spans(trace_dir, names):
+    """``{name: [(start_ns, end_ns), ...]}`` of the named spans on the
+    trace's ``/host:CPU`` plane."""
+    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert paths, "the profiler wrote no trace"
+    data = jax.profiler.ProfileData.from_file(paths[0])
+    out = {}
+    for plane in data.planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def test_driver_step_spans_land_on_the_profiler_host_plane(engine_setup,
+                                                            tmp_path):
+    from repro.api import BranchSession
+    from repro.explore_ctx import ExplorationDriver
+
+    eng = fresh_engine(engine_setup)
+    session = BranchSession(eng, max_batch=8, seed=0)
+    driver = ExplorationDriver(session)
+    hd = session.open([3, 1, 4, 1], max_new_tokens=4)
+    driver.step()                        # builds the decode step untraced
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        driver.step()
+    finally:
+        jax.profiler.stop_trace()
+    session.finish(hd)
+    phases = ("engine.decode_prep", "engine.decode_wait",
+              "engine.decode_post")
+    names = ("driver.step", "engine.decode_step") + phases
+    got = host_spans(str(tmp_path), names)
+    assert {n: len(got.get(n, ())) for n in names} == dict.fromkeys(names, 1)
+    (d0, d1), = got["driver.step"]
+    (s0, s1), = got["engine.decode_step"]
+    assert d0 <= s0 <= s1 <= d1
+    spans = [got[n][0] for n in phases]
+    for a, b in spans:
+        assert s0 <= a <= b <= s1       # each phase inside the step
+    for (_, b), (a, _) in zip(spans, spans[1:]):
+        assert b <= a                   # prep, then wait, then post
+
+
+def test_decode_phases_sum_to_the_decode_step(engine_setup):
+    eng = fresh_engine(engine_setup)
+    root = eng.add_request([2, 7, 1, 8])
+    kids = eng.fork(root, 2)
+    for _ in range(3):
+        eng.decode(kids)
+    h = eng.obs.metrics.snapshot()["histograms"]
+    step = h["engine.decode_step_us"]
+    parts = [h[f"engine.decode_{p}_us"] for p in ("prep", "wait", "post")]
+    assert step["count"] == 3 and all(p["count"] == 3 for p in parts)
+    total = sum(p["sum"] for p in parts)
+    assert total <= step["sum"] + 1e-3
+    assert total >= 0.9 * step["sum"]
+
+
+# ---------------------------------------------------------------------------
+# the scheduler's request times and the prefix cache's prompt share
+# ---------------------------------------------------------------------------
+
+def test_admission_wait_counts_only_queueing(engine_setup):
+    from repro.runtime.scheduler import Scheduler, SchedulerConfig
+
+    eng = fresh_engine(engine_setup, num_pages=5)
+    sched = Scheduler(eng, SchedulerConfig(max_batch=4))
+    prefill = eng.add_request
+
+    def slow_prefill(prompt):
+        time.sleep(0.05)
+        return prefill(prompt)
+
+    eng.add_request = slow_prefill
+    # each request reserves 3 of the 5 pages: r2 waits for r1 to retire
+    r1 = sched.submit(list(range(1, 9)), max_new_tokens=2)
+    r2 = sched.submit(list(range(11, 19)), max_new_tokens=2)
+    sched.run(max_steps=20)
+    assert len(sched.result(r1)) == len(sched.result(r2)) == 10
+    h = eng.obs.metrics.histogram("sched.admission_wait_us")
+    assert h.count == 2
+    # r1 left the queue at once: its wait holds none of its prefill
+    assert h.min < 50_000
+    # r2, held back by the page budget, waited out r1's whole run
+    assert h.max > h.min + 50_000
+
+
+def test_first_token_is_timed_once_per_request(engine_setup):
+    from repro.api import BranchSession
+    from repro.explore_ctx import ExplorationDriver, best_of_n
+
+    eng = fresh_engine(engine_setup, num_pages=256)
+    session = BranchSession(eng, max_batch=16, seed=4)
+    driver = ExplorationDriver(session)
+    exp = driver.explore([5, 6, 7, 8], max_new_tokens=8, policy=best_of_n,
+                         n=4, tokens=3, temperature=1.0)
+    hd = session.open([1, 2, 3], max_new_tokens=3)
+    driver.run()
+    for _ in range(10):
+        session.step()
+    assert exp.result is not None
+    assert session.finish(hd) is not None
+    m = eng.obs.metrics
+    first = m.histogram("sched.first_token_us")
+    assert first.count == 2             # the exploration's turn + the plain
+    assert m.counter("sched.admitted").value == 2
+    # a first token comes after its request left the queue
+    assert first.min >= m.histogram("sched.admission_wait_us").min
+
+
+def test_prompt_token_counters_see_prefix_hits(engine_setup):
+    eng = fresh_engine(engine_setup, prefix_cache=True)
+    total = eng.obs.metrics.counter("engine.prompt_tokens")
+    cached = eng.obs.metrics.counter("engine.prompt_tokens_cached")
+    prompt = list(range(1, 11))
+    a = eng.add_request(prompt)               # a miss: 9 tokens prefilled
+    assert (total.value, cached.value) == (9, 0)
+    _, covered = eng.kv.match_prefix(prompt + [42])
+    assert 0 < covered < 11                   # whole pages of the prompt
+    b = eng.add_request(prompt + [42, 43])    # a hit on those pages
+    assert (total.value, cached.value) == (9 + 11, covered)
+    c = eng.add_request([99, 98, 97, 96, 95])  # another miss
+    assert (total.value, cached.value) == (9 + 11 + 4, covered)
+    for sid in (a, b, c):
+        eng.release(sid)
