@@ -8,7 +8,10 @@ Three layers of evidence, each against a stronger oracle:
   (gather + concat + causal mask), so the oracle is not self-certifying;
 * CoW indirection: the kernel on *pre-copy* pools with a page_map equals
   the plain kernel on pools where the copies were already applied;
-* int8 pages: dequant-inside-the-kernel equals dequant-then-attend.
+* int8 pages: dequant-inside-the-kernel equals dequant-then-attend;
+* the blocked walk: rows ending on every block boundary, CoW redirects
+  and per-page int8 scales inside a block, and no copy past a row's
+  last live page.
 """
 
 import math
@@ -17,10 +20,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.paged_attention.ops import (
     paged_attention,
     paged_chunk_attention,
+)
+from repro.kernels.paged_attention.kernel import (
+    _block_pages,
+    paged_chunk_attention_kernel,
 )
 from repro.kernels.paged_attention.ref import paged_chunk_attention_ref
 
@@ -172,3 +180,135 @@ def test_zero_length_rows_attend_only_to_chunk():
         np.testing.assert_allclose(
             np.asarray(out[:, 0, :, 0]), np.asarray(vn[:, 0]),
             rtol=2e-6, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# the blocked page walk: P pages per block, only a row's live blocks
+# ---------------------------------------------------------------------------
+
+def blocked_case(key, t):
+    """Rows whose lengths sit on every block boundary the walk has.
+
+    P is the kernel's own block size for these shapes, and ``max_pages``
+    is not a multiple of it, so the last block of a full row is partial.
+    """
+    kv, g, hd, page = 2, 2, 128, 16
+    P = _block_pages(page, t * g, 1 << 20)
+    max_pages = 2 * P + P // 2 + 1
+    assert P > 1 and max_pages % P
+    lengths = [0, 1, page - 1, page, P * page - 1, P * page, P * page + 1,
+               max_pages * page]
+    b = len(lengths)
+    q, kn, vn, kp, vp, bt, _, pm = make_case(
+        key, b, t, kv, g, hd, page, 4 * P, max_pages, jnp.float32)
+    return (q, kn, vn, kp, vp, bt, jnp.asarray(lengths, jnp.int32), pm), P
+
+
+def assert_blocked_close(out, expect):
+    """f32 rounding of sums over up to ~1,300 keys, relative to the
+    output's range (the int8 case spreads values over three decades)."""
+    expect = np.asarray(expect)
+    atol = 32 * np.finfo(np.float32).eps * np.max(np.abs(expect))
+    np.testing.assert_allclose(np.asarray(out), expect, rtol=1e-5,
+                               atol=atol)
+
+
+@pytest.mark.parametrize("t", [1, 4, 40])
+def test_blocked_walk_block_boundaries(t):
+    """Lengths 0, 1, page-1, page, P*page-1, P*page, P*page+1 and a full
+    table, mixed in one batch."""
+    args, _ = blocked_case(jax.random.PRNGKey(11), t)
+    assert_blocked_close(paged_chunk_attention(*args, impl="interpret"),
+                         paged_chunk_attention_ref(*args))
+
+
+@pytest.mark.parametrize("t", [1, 4, 40])
+def test_blocked_walk_cow_redirects(t):
+    """A CoW redirect in the middle of a full block and one in the
+    partial last block resolve to their source pages."""
+    (q, kn, vn, kp, vp, bt, lengths, pm), P = blocked_case(
+        jax.random.PRNGKey(12), t)
+    n_pages = kp.shape[0]
+    # pending faults: the full row's page P//2 (block 0) and page 2P+1
+    # (its partial last block), and row 6's page P (the one live page
+    # of its last block); fresh dst pages hold garbage
+    full, partial = 7, 6
+    dst = jnp.asarray([n_pages - 1, n_pages - 2, n_pages - 3], jnp.int32)
+    src = jnp.asarray([bt[full, P // 2], bt[full, 2 * P + 1],
+                       bt[partial, P]], jnp.int32)
+    bt = bt.at[full, P // 2].set(dst[0])
+    bt = bt.at[full, 2 * P + 1].set(dst[1])
+    bt = bt.at[partial, P].set(dst[2])
+    pm_redir = pm.at[dst].set(src)
+    garbage = jnp.full(kp.shape[1:], 1e4, kp.dtype)
+    kp_pre = kp.at[dst].set(garbage)
+    vp_pre = vp.at[dst].set(garbage)
+    out = paged_chunk_attention(q, kn, vn, kp_pre, vp_pre, bt, lengths,
+                                pm_redir, impl="interpret")
+    post = paged_chunk_attention_ref(q, kn, vn, kp_pre.at[dst].set(kp[src]),
+                                     vp_pre.at[dst].set(vp[src]), bt,
+                                     lengths, pm)
+    assert_blocked_close(out, post)
+
+
+@pytest.mark.parametrize("t", [1, 4, 40])
+def test_blocked_walk_int8_scales_per_page(t):
+    """Within one block every page takes its own K and V scale."""
+    (q, kn, vn, kp, vp, bt, lengths, pm), _ = blocked_case(
+        jax.random.PRNGKey(13), t)
+    # spread the pages' ranges over three decades so that neighbouring
+    # pages of a block carry very different scales
+    spread = jnp.exp(jax.random.uniform(jax.random.PRNGKey(14),
+                                        (kp.shape[0], 1, 1, 1),
+                                        minval=-3.0, maxval=3.0))
+    kp, vp = kp * spread, vp * spread[::-1]
+    ks = jnp.max(jnp.abs(kp), axis=(2, 3)) / 127.0 + 1e-8
+    vs = jnp.max(jnp.abs(vp), axis=(2, 3)) / 127.0 + 1e-8
+    assert float(jnp.max(ks) / jnp.min(ks)) > 100.0
+    kq = jnp.round(kp / ks[:, :, None, None]).astype(jnp.int8)
+    vq = jnp.round(vp / vs[:, :, None, None]).astype(jnp.int8)
+    out = paged_chunk_attention(q, kn, vn, kq, vq, bt, lengths, pm, ks, vs,
+                                impl="interpret")
+    assert_blocked_close(out, paged_chunk_attention_ref(
+        q, kn, vn, kq, vq, bt, lengths, pm, ks, vs))
+
+
+@pytest.mark.parametrize("t", [1, 4, 40])
+def test_blocked_walk_copies_only_live_pages(t):
+    """No copy reads a table entry past a row's last live page, and the
+    dead tail of a partial block never reaches the output.
+
+    Table entries past each row's live pages resolve to a page far
+    outside the pool, and the TPU interpreter raises on an out-of-bounds
+    read and fills memory nothing wrote with NaN."""
+    (q, kn, vn, kp, vp, bt, lengths, pm), _ = blocked_case(
+        jax.random.PRNGKey(15), t)
+    n_pages, page = kp.shape[0], kp.shape[2]
+    poison = n_pages - 1
+    bt = jnp.where(bt == poison, 0, bt)
+    live = (lengths[:, None] + page - 1) // page
+    bt_poisoned = jnp.where(jnp.arange(bt.shape[1])[None, :] < live, bt,
+                            poison)
+    strict = pltpu.InterpretParams(out_of_bounds_reads="raise",
+                                   uninitialized_memory="nan")
+    out = paged_chunk_attention_kernel(
+        q, kn, vn, kp, vp, bt_poisoned, lengths,
+        pm.at[poison].set(n_pages + 1000), interpret=strict)
+    assert_blocked_close(out, paged_chunk_attention_ref(
+        q, kn, vn, kp, vp, bt, lengths, pm))
+
+
+@pytest.mark.parametrize("t", [1, 4])
+def test_blocked_walk_stops_at_the_table(t):
+    """A length past the table's last page attends to the whole table,
+    as the reference does, and reads no table entry or page beyond it."""
+    (q, kn, vn, kp, vp, bt, lengths, pm), _ = blocked_case(
+        jax.random.PRNGKey(16), t)
+    full = bt.shape[1] * kp.shape[2]
+    over = lengths.at[-1].set(full + 5 * kp.shape[2] + 3)
+    strict = pltpu.InterpretParams(out_of_bounds_reads="raise",
+                                   uninitialized_memory="nan")
+    out = paged_chunk_attention_kernel(q, kn, vn, kp, vp, bt, over, pm,
+                                       interpret=strict)
+    assert_blocked_close(out, paged_chunk_attention_ref(
+        q, kn, vn, kp, vp, bt, lengths.at[-1].set(full), pm))
