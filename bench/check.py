@@ -12,17 +12,19 @@ served before it.  Two numbers:
 ``greedy_argmax_mismatches`` served greedy tokens that are not the
                     first of the logits that produced them (exact).
 
-The control puts the reference computed in float8 (see
-:mod:`bench.reference`) in the program's place, at the same positions.
+The reference is the configuration's family's (``logits`` of
+``bench/families/<family>.py``).  The control puts the reference
+computed in float8 in the program's place, at the same positions.
 """
 
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from bench import generator, reference
+from bench import generator
 
 NO_READING = 1e30      # what a run that compared nothing reports
 
@@ -55,18 +57,18 @@ def rel_err(ref: np.ndarray, got: np.ndarray) -> np.ndarray:
             / np.linalg.norm(centred, axis=-1))
 
 
-def compare(model: dict, seed: int, seqs, control: Optional[str] = None
-            ) -> Dict[str, float]:
+def compare(fam: ModuleType, model: dict, seed: int, seqs,
+            control: Optional[str] = None) -> Dict[str, float]:
     """Both numbers over ``seqs``, each ``(prompt, served tokens, the
-    program's logits at the served positions)``; with ``control`` its
-    logits stand in for the program's."""
+    program's logits at the served positions)``, against the family
+    ``fam``'s reference; with ``control`` its logits in that precision
+    stand in for the program's."""
     worst, mism = 0.0, 0
     for prompt, served, rows in seqs:
         tokens, pos = _rows(prompt, served)
-        ref = np.asarray(reference.logits(model, seed, tokens, pos))
+        ref = np.asarray(fam.logits(model, seed, tokens, pos))
         got = (np.asarray(rows, np.float32) if control is None else
-               np.asarray(reference.logits(model, seed, tokens, pos,
-                                           control)))
+               np.asarray(fam.logits(model, seed, tokens, pos, control)))
         worst = max(worst, float(rel_err(ref, got).max()))
         mism += int((got.argmax(-1) != np.asarray(served)).sum())
     return {"logit_rel_err": worst, "greedy_argmax_mismatches": mism}

@@ -2,10 +2,12 @@
 
 Everything a cell needs is found by name from ``BENCHMARK.json``:
 ``bench/configs/<config>.json`` (the deployment: model sizes and the
-engine's pool and batch), ``bench/traffic/<traffic>.json`` (the mix,
-read by :mod:`bench.generator`), ``bench/cells/<cell>.json`` (the
-limits of the correctness check) and ``bench/metrics/<metric>.py`` (one
-reader per per-layer metric).
+engine's pool and batch), ``bench/families/<family>.py`` (the model
+family the configuration names: its weights, plain reference and work
+counts), ``bench/traffic/<traffic>.json`` (the mix, read by
+:mod:`bench.generator`), ``bench/cells/<cell>.json`` (the limits of the
+correctness check) and ``bench/metrics/<metric>.py`` (one reader per
+per-layer metric).
 
 The window drives ``ExplorationDriver.step()``; under it run
 ``BranchSession``, the ``Scheduler`` and the ``ServeEngine`` (prefill
@@ -17,6 +19,7 @@ attributes of the objects it built — no file of the program changes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
@@ -26,12 +29,13 @@ import sys
 import time
 from contextlib import nullcontext
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
 
-from bench import generator, trace as T, weights as W, work
+from bench import generator, trace as T, work
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -57,11 +61,20 @@ def load_spec(cell: str, root: Path = ROOT) -> dict:
         raise Refused(f"no workload {cell!r} in BENCHMARK.json")
     w = wl[cell]
     bench = root / "bench"
+    conf = json.loads(
+        (bench / "configs" / f"{w['config']}.json").read_text())
+    if "family" not in conf:
+        raise Refused(f"config {w['config']} names no family: give it "
+                      '"family": "<name>" for bench/families/<name>.py')
+    fam = bench / "families" / f"{conf['family']}.py"
+    if not fam.is_file():
+        raise Refused(f"config {w['config']} names the family "
+                      f"{conf['family']!r}, and there is no {fam}")
     return {
         "workload": w,
         "benchmark": spec,
-        "config": json.loads(
-            (bench / "configs" / f"{w['config']}.json").read_text()),
+        "config": conf,
+        "family": family(fam),
         "traffic": json.loads(
             (bench / "traffic" / f"{w['traffic']}.json").read_text()),
         "cell": json.loads((bench / "cells" / f"{cell}.json").read_text()),
@@ -69,16 +82,42 @@ def load_spec(cell: str, root: Path = ROOT) -> dict:
     }
 
 
-def metric_reader(metrics_dir: Path, name: str) -> Callable:
-    """``bench/metrics/<name>.py``'s ``read(run)``."""
-    path = metrics_dir / f"{name}.py"
-    mod_name = "bench_metric_" + name.replace(".", "_").replace("-", "_")
+def _load(path: Path, prefix: str) -> ModuleType:
+    mod_name = prefix + path.stem.replace(".", "_").replace("-", "_")
     s = importlib.util.spec_from_file_location(mod_name, path)
     if s is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(s)
     s.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(metrics_dir: Path, name: str) -> Callable:
+    """``bench/metrics/<name>.py``'s ``read(run)``."""
+    return _load(metrics_dir / f"{name}.py", "bench_metric_").read
+
+
+@functools.lru_cache(maxsize=None)
+def family(path: Path) -> ModuleType:
+    """A model family, ``bench/families/<family>.py``, loaded once per
+    path (its jitted functions then compile once per process).  It
+    provides, for a configuration's ``model`` block ``m``:
+
+    ``make(m, seed)``, ``layer(m, seed, i)``, ``outer(m, seed)``
+        the weights from the seed, in the configuration's dtype: all of
+        them with the layers stacked (the pytree the program serves),
+        layer ``i`` alone, and those outside the layers;
+    ``logits(m, seed, tokens, positions, precision)``
+        the plain reference's float32 logits at ``positions``, and with
+        ``precision="fp8"`` the control's;
+    ``token_flops(m, n_ctx)``
+        model operations to decode one token over ``n_ctx`` tokens;
+    ``attention_work(m, rows, kv_dtype)``
+        ``(operations, bytes)`` of each paged-attention call, one per
+        layer, that one decode or suffix call over ``rows`` (each
+        ``(n_ctx, t)``) makes.
+    """
+    return _load(path, "bench_family_")
 
 
 def arch_config(model: dict, log: Callable = lambda *_: None):
@@ -100,9 +139,11 @@ def arch_config(model: dict, log: Callable = lambda *_: None):
 
 
 class Recorder:
-    """What the wrappers see."""
+    """What the wrappers see.  The model's work is counted by the
+    configuration's family while a trace records."""
 
-    def __init__(self) -> None:
+    def __init__(self, fam: ModuleType, model: dict, kv_dtype: str) -> None:
+        self.fam, self.model, self.kv_dtype = fam, model, kv_dtype
         self.window: Optional[tuple] = None
         self.first_t: Dict[int, float] = {}
         self.last_t: Dict[int, float] = {}
@@ -122,6 +163,14 @@ class Recorder:
     def in_window(self, t: float) -> bool:
         return self.window is not None and \
             self.window[0] <= t <= self.window[1]
+
+    def count(self, rows: List[tuple], decode: bool) -> None:
+        """One decode or suffix call over ``rows``, each ``(n_ctx, t)``."""
+        self.attn_work += self.fam.attention_work(self.model, rows,
+                                                  self.kv_dtype)
+        if decode:
+            self.decode_flops += sum(self.fam.token_flops(self.model, n)
+                                     for n, _ in rows)
 
     def on_event(self, event: str, **_: Any) -> None:
         """A program compiled, or loaded from the persistent cache."""
@@ -150,7 +199,7 @@ def build(spec: dict, seed: int, *, impl: str, log: Callable):
     model = conf["model"]
     cfg = arch_config(model, log)
     eng = conf["engine"]
-    params = W.make(model, seed)
+    params = spec["family"].make(model, seed)
     jax.block_until_ready(params)
     engine = ServeEngine(
         Model(cfg, remat=False), params, num_pages=eng["num_pages"],
@@ -163,20 +212,16 @@ def build(spec: dict, seed: int, *, impl: str, log: Callable):
     return engine, session, driver
 
 
-def instrument(engine, session, driver, rec: Recorder, annotate: Callable,
-               model: dict, kv_dtype: str) -> Callable:
+def instrument(engine, session, driver, rec: Recorder,
+               annotate: Callable) -> Callable:
     """Wrap each layer's entry on its instance; returns ``driver.step``
     timed and annotated."""
-    n_layers = model["num_layers"]
     orig_decode = engine.decode
 
     def decode(seq_ids, **kw):
         if rec.tracing:
-            ctx = [engine.kv.length(s) for s in seq_ids]
-            fl, by = work.paged_attention_work(
-                model, [(c, 1) for c in ctx], kv_dtype)
-            rec.attn_work += [(fl, by)] * n_layers
-            rec.decode_flops += sum(work.token_flops(model, c) for c in ctx)
+            rec.count([(engine.kv.length(s), 1) for s in seq_ids],
+                      decode=True)
         with annotate("engine.decode"):
             t0 = time.perf_counter()
             out = orig_decode(seq_ids, **kw)
@@ -212,9 +257,7 @@ def instrument(engine, session, driver, rec: Recorder, annotate: Callable,
 
     def chunk_prefill(sid, tokens, covered):
         if rec.tracing:
-            fl, by = work.paged_attention_work(
-                model, [(covered, len(tokens) - covered)], kv_dtype)
-            rec.attn_work += [(fl, by)] * n_layers
+            rec.count([(covered, len(tokens) - covered)], decode=False)
         return orig_chunk(sid, tokens, covered)
 
     orig_admit = session.admit
@@ -323,7 +366,6 @@ class Run:
         self.rate = rate
         self.control = control
         self.log = log
-        self.rec = Recorder()
 
     def go(self) -> dict:
         out, self.state = self._serve()
@@ -343,12 +385,13 @@ class Run:
     def _serve(self):
         from bench.clients import AgentClient
 
-        spec, rec = self.spec, self.rec
+        spec = self.spec
         conf, traffic = spec["config"], dict(spec["traffic"])
         if self.rate is not None:
             traffic["session_rate_per_s"] = self.rate
         model = conf["model"]
         kv_dtype = conf["engine"]["kv_dtype"] or model["dtype"]
+        self.rec = rec = Recorder(spec["family"], model, kv_dtype)
         jax.monitoring.register_event_listener(rec.on_event)
         engine, session, driver = build(spec, self.seed, impl=self.impl,
                                         log=self.log)
@@ -356,8 +399,7 @@ class Run:
             raise Refused(f"attention resolved to {engine.attn_impl!r}, "
                           "not 'pallas'")
         annotate = annotator(self.trace)
-        step = instrument(engine, session, driver, rec, annotate, model,
-                          kv_dtype)
+        step = instrument(engine, session, driver, rec, annotate)
         sched = generator.Schedule(traffic, self.seed, model["vocab_size"])
         client = AgentClient(traffic, sched, driver, annotate)
         warm_up(engine, client, traffic, conf["engine"]["max_batch"],
@@ -597,7 +639,8 @@ class Run:
                 "greedy_argmax_mismatches": check.NO_READING}
         if seqs:
             t0 = time.perf_counter()
-            read = check.compare(self.spec["config"]["model"], self.seed,
+            read = check.compare(self.spec["family"],
+                                 self.spec["config"]["model"], self.seed,
                                  seqs, control)
             self.log(f"reference: {len(seqs)} turns, "
                      f"{sum(len(g) for _, g, _ in seqs)} served tokens, "

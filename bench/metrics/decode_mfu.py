@@ -1,7 +1,7 @@
 """Model FLOP utilization of decoding over the traced stretch, %: tokens
 decoded times the model operations per token at each row's context
-(``bench.work.token_flops``), over the stretch's seconds times the
-chip's bf16 peak."""
+(the configuration's family's ``token_flops``), over the stretch's
+seconds times the chip's bf16 peak."""
 
 
 def read(run):

@@ -1,12 +1,10 @@
-"""The plain reference: a dense GQA transformer forward in float32.
+"""The plain reference: the pieces every family's float32 forward shares.
 
-Written from the published description of the architecture family that
-both configurations belong to (pre-norm decoder; RMSNorm; rotary
-position embedding on the rotate-half convention; grouped-query
-attention with optional q/k/v biases; SwiGLU feed-forward; tied or
-untied output head).  It imports nothing of the program under test and
-takes nothing the program made: the weights are made again from the
-seed by :mod:`bench.weights`, the sizes come from the configuration's
+A family (``bench/families/<family>.py``) writes its layer's forward
+from the published description of its architecture with these pieces,
+and :func:`forward` runs it.  Nothing here imports the program under
+test or takes anything the program made: the weights are made again
+from the seed by the family, the sizes come from the configuration's
 file.
 
 Every matrix product runs at ``Precision.HIGHEST``: on a TPU a float32
@@ -25,13 +23,13 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Any, Dict
+from typing import Any, Callable, Dict, Iterable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as W
+from bench.weights import freeze
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512          # query rows per attention block
@@ -39,28 +37,29 @@ LEN_BUCKET = 1024      # sequences are padded to a multiple of this
 POS_BUCKET = 128       # logit rows are gathered in multiples of this
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
+FP8 = {"fp32": False, "fp8": True}     # precision -> operands in float8
 
 
-def _q8(x: jax.Array, axis) -> jax.Array:
+def q8(x: jax.Array, axis) -> jax.Array:
     """Round to float8 e4m3 with an amax scale over ``axis``."""
     s = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / F8_MAX
     s = jnp.where(s > 0, s, 1.0)
     return (x / s).astype(F8).astype(jnp.float32) * s
 
 
-def _mm(eq: str, a: jax.Array, b: jax.Array, fp8: bool,
-        a_axis=-1, b_axis=None) -> jax.Array:
+def mm(eq: str, a: jax.Array, b: jax.Array, fp8: bool,
+       a_axis=-1, b_axis=None) -> jax.Array:
     if fp8:
-        a = _q8(a, a_axis)
-        b = _q8(b, b_axis)
+        a = q8(a, a_axis)
+        b = q8(b, b_axis)
     return jnp.einsum(eq, a, b, precision=HI)
 
 
-def _rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+def rms(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def _rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+def rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     """x: [S, heads, hd]; rotate-half rotary embedding."""
     hd = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
@@ -70,49 +69,28 @@ def _rope(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _layer_fwd(frozen: tuple, fp8: bool, lp: Dict[str, Any],
-               h: jax.Array) -> jax.Array:
-    m = dict(frozen)
-    f32 = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), lp)
-    S = h.shape[0]
-    nh, kvh, hd = m["num_heads"], m["num_kv_heads"], m["head_dim"]
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     fp8: bool) -> jax.Array:
+    """Grouped-query causal attention, one block of ``Q_BLOCK`` queries
+    at a time: q ``[S, heads, hd]``, k and v ``[S, kv heads, hd]``."""
+    S, nh, hd = q.shape
+    kvh = k.shape[1]
     g = nh // kvh
     pos = jnp.arange(S)
-    a = f32["attn"]
-    x = _rms(h, f32["ln1"], m["norm_eps"])
-    q = _mm("sd,dhk->shk", x, a["wq"], fp8, b_axis=None)
-    k = _mm("sd,dhk->shk", x, a["wk"], fp8, b_axis=None)
-    v = _mm("sd,dhk->shk", x, a["wv"], fp8, b_axis=None)
-    if m["qkv_bias"]:
-        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = _rope(q, pos, m["rope_theta"])
-    k = _rope(k, pos, m["rope_theta"])
     qg = q.reshape(S // Q_BLOCK, Q_BLOCK, kvh, g, hd)
     scale = 1.0 / math.sqrt(hd)
 
     def block(args):
         qb, i = args                                  # [B, kv, g, hd]
-        s = _mm("qkgh,skh->kgqs", qb, k, fp8, a_axis=-1, b_axis=-1) * scale
+        s = mm("qkgh,skh->kgqs", qb, k, fp8, a_axis=-1, b_axis=-1) * scale
         qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
         s = jnp.where(pos[None, None, None, :] <= qpos[None, None, :, None],
                       s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        return _mm("kgqs,skh->qkgh", p, v, fp8, a_axis=-1, b_axis=0)
+        return mm("kgqs,skh->qkgh", p, v, fp8, a_axis=-1, b_axis=0)
 
     o = jax.lax.map(block, (qg, jnp.arange(S // Q_BLOCK)))
-    o = o.reshape(S, nh, hd)
-    h = h + _mm("shk,hkd->sd", o, a["wo"], fp8, a_axis=(-2, -1))
-    x = _rms(h, f32["ln2"], m["norm_eps"])
-    mp = f32["mlp"]
-    up = _mm("sd,df->sf", x, mp["wu"], fp8)
-    if m["mlp_activation"] == "swiglu":
-        gate = jax.nn.silu(_mm("sd,df->sf", x, mp["wg"], fp8))
-    elif m["mlp_activation"] == "geglu":
-        gate = jax.nn.gelu(_mm("sd,df->sf", x, mp["wg"], fp8))
-    else:
-        raise ValueError(m["mlp_activation"])
-    return h + _mm("sf,fd->sd", gate * up, mp["wd"], fp8)
+    return o.reshape(S, nh, hd)
 
 
 @partial(jax.jit, static_argnums=(0, 1))
@@ -125,21 +103,25 @@ def _embed(frozen: tuple, S: int, outer: Dict[str, Any],
 def _head(frozen: tuple, fp8: bool, outer: Dict[str, Any], h: jax.Array,
           rows: jax.Array) -> jax.Array:
     m = dict(frozen)
-    x = _rms(h[rows], outer["final_norm"].astype(jnp.float32),
-             m["norm_eps"])
+    x = rms(h[rows], outer["final_norm"].astype(jnp.float32), m["norm_eps"])
     if m["tie_embeddings"]:
         w = outer["embed"].astype(jnp.float32)
-        return _mm("sd,vd->sv", x, w, fp8, b_axis=None)
-    return _mm("sd,dv->sv", x, outer["lm_head"].astype(jnp.float32), fp8)
+        return mm("sd,vd->sv", x, w, fp8, b_axis=None)
+    return mm("sd,dv->sv", x, outer["lm_head"].astype(jnp.float32), fp8)
 
 
-def logits(m: dict, seed: int, tokens, positions,
-           precision: str = "fp32") -> jax.Array:
+def forward(m: dict, tokens, positions, fp8: bool, outer: Dict[str, Any],
+            layers: Iterable[Callable[[jax.Array], jax.Array]]
+            ) -> jax.Array:
     """Logits ``[len(positions), vocab]`` (float32, on the device) of the
     sequence ``tokens`` at each of ``positions``: row ``i`` is the
-    distribution of the token that follows ``tokens[positions[i]]``."""
-    fp8 = {"fp32": False, "fp8": True}[precision]
-    frozen = W._freeze(m)
+    distribution of the token that follows ``tokens[positions[i]]``.
+
+    ``outer`` holds ``embed``, ``final_norm`` and, untied, ``lm_head``;
+    ``layers`` yields each layer's forward ``h -> h`` in order, made when
+    it is reached, so one layer's weights are on the device at a time.
+    """
+    frozen = freeze(m)
     tokens = np.asarray(tokens, np.int32)
     positions = np.asarray(positions, np.int32)
     S = -(-len(tokens) // LEN_BUCKET) * LEN_BUCKET
@@ -148,8 +130,7 @@ def logits(m: dict, seed: int, tokens, positions,
     toks[:len(tokens)] = tokens
     rows = np.zeros(P, np.int32)
     rows[:len(positions)] = positions
-    outer = W.outer(m, seed)
     h = _embed(frozen, S, outer, jnp.asarray(toks))
-    for i in range(m["num_layers"]):
-        h = _layer_fwd(frozen, fp8, W.layer(m, seed, i), h)
+    for layer_fwd in layers:
+        h = layer_fwd(h)
     return _head(frozen, fp8, outer, h, jnp.asarray(rows))[:len(positions)]

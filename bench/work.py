@@ -1,10 +1,13 @@
-"""Operations and bytes that the served model's work needs, from shapes.
+"""Operations and bytes that the served model's work needs, from shapes:
+the chip's peaks and the arithmetic every family shares.
 
 Every function counts the logical work of the algorithm, not what a
 kernel's tiling happens to touch: a row whose context holds ``n`` tokens
 reads ``n`` tokens of K and V, however the pages around them are laid
 out.  The model dict is a configuration's ``model`` block
-(``bench/configs/<name>.json``).
+(``bench/configs/<name>.json``).  What a whole token or a whole call
+costs depends on the architecture, and its family
+(``bench/families/<family>.py``) adds it up from these pieces.
 """
 
 from __future__ import annotations
@@ -30,15 +33,6 @@ def elem_bytes(dtype: str) -> int:
     return {"bfloat16": 2, "float16": 2, "float32": 4, "int8": 1}[dtype]
 
 
-def layer_matmul_params(m: dict) -> int:
-    """Weights one token multiplies through in one layer."""
-    d, h, kv, hd, f = (m["d_model"], m["num_heads"], m["num_kv_heads"],
-                       m["head_dim"], m["d_ff"])
-    attn = d * h * hd + 2 * d * kv * hd + h * hd * d
-    mlp = (3 if m["mlp_activation"] in ("swiglu", "geglu") else 2) * d * f
-    return attn + mlp
-
-
 def attention_flops(m: dict, n_ctx: int, t: int = 1) -> float:
     """Score and value products of ``t`` new tokens over ``n_ctx`` cached
     ones plus their own causal chunk, in one layer."""
@@ -47,17 +41,9 @@ def attention_flops(m: dict, n_ctx: int, t: int = 1) -> float:
     return 4.0 * h * hd * pairs
 
 
-def token_flops(m: dict, n_ctx: int) -> float:
-    """Model operations to decode one token whose context holds
-    ``n_ctx`` tokens: every layer's weights, attention, and the head."""
-    per_layer = 2.0 * layer_matmul_params(m) + attention_flops(m, n_ctx)
-    head = 2.0 * m["d_model"] * m["vocab_size"]
-    return m["num_layers"] * per_layer + head
-
-
 def paged_attention_work(m: dict, rows: Iterable[Tuple[int, int]],
                          kv_dtype: str) -> Tuple[float, float]:
-    """(operations, bytes) of one paged-attention call per layer, summed
+    """(operations, bytes) of one layer's paged-attention call, summed
     over its rows, each ``(n_ctx, t)``: ``t`` query tokens against
     ``n_ctx`` cached tokens plus the chunk itself.
 

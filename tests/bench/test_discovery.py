@@ -1,6 +1,6 @@
-"""The harness finds every configuration, traffic mix, cell and metric by
-name from files; traffic is a pure function of its parameters and the
-seed; a run without a TPU is refused."""
+"""The harness finds every configuration, model family, traffic mix, cell
+and metric by name from files; traffic is a pure function of its
+parameters and the seed; a run without a TPU is refused."""
 
 import json
 import os
@@ -12,7 +12,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import generator, harness
+from bench import check, generator, harness
 
 ROOT = Path(__file__).resolve().parents[2]
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
@@ -32,6 +32,12 @@ def test_every_cell_resolves_to_its_files(cell):
     assert set(spec["cell"]["limits"]) >= {"logit_rel_err",
                                           "greedy_argmax_mismatches"}
     harness.arch_config(spec["config"]["model"])
+    fam = spec["family"]
+    assert Path(fam.__file__) == (ROOT / "bench" / "families" /
+                                  f"{spec['config']['family']}.py")
+    for fn in ("make", "layer", "outer", "logits", "token_flops",
+               "attention_work"):
+        assert callable(getattr(fam, fn))
     for m in SPEC["end_to_end"]:
         assert m["name"] in harness.END_TO_END
     for m in SPEC["per_layer"]:
@@ -41,7 +47,7 @@ def test_every_cell_resolves_to_its_files(cell):
 
 def test_a_new_cell_and_metric_are_found_by_name(tmp_path):
     (tmp_path / "bench").mkdir()
-    for d in ("configs", "traffic", "cells"):
+    for d in ("configs", "families", "traffic", "cells"):
         shutil.copytree(ROOT / "bench" / d, tmp_path / "bench" / d)
     (tmp_path / "bench" / "metrics").mkdir()
     spec = json.loads(json.dumps(SPEC))
@@ -71,6 +77,98 @@ def test_a_new_cell_and_metric_are_found_by_name(tmp_path):
     assert read({"requests_admitted": 0}) is None
     with pytest.raises(harness.Refused):
         harness.load_spec("no-such-cell", root=tmp_path)
+
+
+TOY_FAMILY = """
+import numpy as np
+
+CALLS = []
+
+
+def make(m, seed):
+    return {"seed": seed}
+
+
+def layer(m, seed, i):
+    return {"i": i}
+
+
+def outer(m, seed):
+    return {}
+
+
+def logits(m, seed, tokens, positions, precision="fp32"):
+    CALLS.append(precision)
+    pos = np.asarray(positions)
+    out = np.zeros((len(pos), m["vocab_size"]), np.float32)
+    out[np.arange(len(pos)), np.asarray(tokens)[pos + 1]] = 1.0
+    return out if precision == "fp32" else np.roll(out, 1, -1)
+
+
+def token_flops(m, n_ctx):
+    return 1000.0 + n_ctx
+
+
+def attention_work(m, rows, kv_dtype):
+    rows = list(rows)
+    return [(float(sum(n for n, _ in rows)), float(len(rows)))] * 3
+"""
+
+
+def toy_root(tmp_path, conf_extra):
+    """A checkout under ``tmp_path`` with one cell whose configuration
+    is ``toy-model`` (``conf_extra`` merged into it)."""
+    for d in ("traffic", "cells"):
+        shutil.copytree(ROOT / "bench" / d, tmp_path / "bench" / d)
+    (tmp_path / "bench" / "configs").mkdir()
+    (tmp_path / "bench" / "families").mkdir()
+    (tmp_path / "bench" / "families" / "toy.py").write_text(TOY_FAMILY)
+    conf = json.loads((ROOT / "bench" / "configs" /
+                       "qwen2-1.5b.json").read_text())
+    conf.pop("family")
+    conf.update(name="toy-model", **conf_extra)
+    conf["model"] = dict(conf["model"], vocab_size=16)
+    (tmp_path / "bench" / "configs" / "toy-model.json").write_text(
+        json.dumps(conf))
+    (tmp_path / "bench" / "cells" / "toy-cell.json").write_text(
+        (ROOT / "bench" / "cells" / "qwen2-agent-fanout.json").read_text())
+    spec = json.loads(json.dumps(SPEC))
+    w = dict(spec["workloads"][0], name="toy-cell", config="toy-model")
+    spec["workloads"].append(w)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    return tmp_path
+
+
+def test_a_new_family_is_found_by_name(tmp_path):
+    before = sorted(p.name for p in (ROOT / "bench" / "families").iterdir())
+    spec = harness.load_spec("toy-cell",
+                             root=toy_root(tmp_path, {"family": "toy"}))
+    fam = spec["family"]
+    assert Path(fam.__file__) == tmp_path / "bench" / "families" / "toy.py"
+    model = spec["config"]["model"]
+    # the check compares with the family's reference, and its control
+    seqs = [([1, 2, 3], [4, 5], np.eye(16, dtype=np.float32)[[4, 5]])]
+    assert check.compare(fam, model, 7, seqs) == {
+        "logit_rel_err": 0.0, "greedy_argmax_mismatches": 0}
+    ctl = check.compare(fam, model, 7, seqs, "fp8")
+    assert ctl["greedy_argmax_mismatches"] == 2
+    assert fam.CALLS == ["fp32", "fp32", "fp8"]
+    # the work counted in a traced stretch is the family's
+    rec = harness.Recorder(fam, model, "bfloat16")
+    rec.count([(10, 1), (20, 1)], decode=True)
+    rec.count([(30, 4)], decode=False)
+    assert rec.attn_work == [(30.0, 2.0)] * 3 + [(30.0, 1.0)] * 3
+    assert rec.decode_flops == 2030.0
+    assert sorted(p.name for p in
+                  (ROOT / "bench" / "families").iterdir()) == before
+
+
+@pytest.mark.parametrize("extra", [{}, {"family": "no-such-family"}],
+                         ids=["no-family-key", "no-family-file"])
+def test_a_config_without_a_family_file_is_refused(tmp_path, extra):
+    root = toy_root(tmp_path, extra)
+    with pytest.raises(harness.Refused, match="family"):
+        harness.load_spec("toy-cell", root=root)
 
 
 TRAFFIC = sorted(p.stem for p in (ROOT / "bench" / "traffic").glob("*.json"))

@@ -1,6 +1,8 @@
 """The plain reference against the served model at a small size: the
 dense prefill's logits and the paged decode's logits, on weights that
-the benchmark makes from a seed (one past 32 bits)."""
+the benchmark makes from a seed (one past 32 bits).  Weights and
+reference come from the dense family, found by name as a run finds
+it."""
 
 import dataclasses
 
@@ -9,9 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, reference, weights
+from bench import check, harness
 
 SEED = (1 << 33) + 12345
+DENSE = harness.family(harness.ROOT / "bench" / "families" / "dense.py")
 
 MODELS = {
     "qwen2-like": dict(
@@ -44,14 +47,14 @@ def program(m):
 
 def test_layer_by_layer_weights_match_the_stacked_ones():
     m = MODELS["qwen2-like"]
-    p = weights.make(m, SEED)
+    p = DENSE.make(m, SEED)
     for i in range(m["num_layers"]):
-        one = weights.layer(m, SEED, i)
+        one = DENSE.layer(m, SEED, i)
         same = jax.tree_util.tree_map(
             lambda a, b: bool((a[i] == b).all()), p["layers"], one)
         assert all(jax.tree_util.tree_leaves(same))
-    assert bool((weights.outer(m, SEED)["embed"] == p["embed"]).all())
-    other = weights.make(m, SEED + 1)
+    assert bool((DENSE.outer(m, SEED)["embed"] == p["embed"]).all())
+    other = DENSE.make(m, SEED + 1)
     assert not bool((other["embed"] == p["embed"]).all())
 
 
@@ -59,10 +62,10 @@ def test_layer_by_layer_weights_match_the_stacked_ones():
 def test_reference_matches_the_dense_prefill(name):
     m = MODELS[name]
     model = program(m)
-    params = weights.make(m, SEED)
+    params = DENSE.make(m, SEED)
     toks = np.random.default_rng(0).integers(1, m["vocab_size"], 37)
     got, _ = model.prefill(params, jnp.asarray(toks)[None])
-    ref = np.asarray(reference.logits(m, SEED, toks, [36]))[0]
+    ref = np.asarray(DENSE.logits(m, SEED, toks, [36]))[0]
     got = np.asarray(got[0, -1], np.float32)
     assert np.abs(got - ref).max() <= REL_TOL * np.abs(ref).max()
 
@@ -73,7 +76,7 @@ def test_reference_matches_paged_decode(name):
 
     m = MODELS[name]
     model = program(m)
-    params = weights.make(m, SEED)
+    params = DENSE.make(m, SEED)
     eng = ServeEngine(model, params, num_pages=64, page_size=8,
                       max_pages_per_seq=16, attn_impl="fused_ref")
     prompt = np.random.default_rng(1).integers(1, m["vocab_size"], 21)
@@ -84,12 +87,12 @@ def test_reference_matches_paged_decode(name):
         rows.append(np.asarray(eng.last_logits[0], np.float32))
     toks = eng.tokens(sid)
     pos = np.arange(len(prompt) - 1, len(toks) - 1)
-    ref = np.asarray(reference.logits(m, SEED, toks, pos))
+    ref = np.asarray(DENSE.logits(m, SEED, toks, pos))
     got = np.stack(rows)
     assert np.abs(got - ref).max() <= REL_TOL * np.abs(ref).max()
     # and the check's own numbers read the same served positions
-    read = check.compare(m, SEED, [(prompt.tolist(), toks[len(prompt):],
-                                    got)])
+    read = check.compare(DENSE, m, SEED,
+                         [(prompt.tolist(), toks[len(prompt):], got)])
     assert read["logit_rel_err"] <= REL_TOL
     assert read["greedy_argmax_mismatches"] == 0
 
@@ -97,11 +100,11 @@ def test_reference_matches_paged_decode(name):
 def test_float8_control_departs_further_than_the_program():
     m = MODELS["qwen2-like"]
     model = program(m)
-    params = weights.make(m, SEED)
+    params = DENSE.make(m, SEED)
     toks = np.random.default_rng(2).integers(1, m["vocab_size"], 64)
     pos = np.arange(32, 63)
-    ref = np.asarray(reference.logits(m, SEED, toks, pos))
-    low = np.asarray(reference.logits(m, SEED, toks, pos, "fp8"))
+    ref = np.asarray(DENSE.logits(m, SEED, toks, pos))
+    low = np.asarray(DENSE.logits(m, SEED, toks, pos, "fp8"))
     err_low = np.abs(low - ref).max()
     # the program in bf16 at the last position
     last, _ = model.prefill(params, jnp.asarray(toks[:63])[None])

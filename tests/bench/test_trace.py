@@ -8,8 +8,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import trace as T
-from bench import work
+from bench import harness, trace as T, work
 
 FIXTURE = Path(__file__).parent / "fixtures" / "v5e_decode_trace.json.gz"
 
@@ -106,12 +105,15 @@ def test_work_arithmetic():
     m = {"num_layers": 2, "d_model": 8, "num_heads": 4, "num_kv_heads": 2,
          "head_dim": 2, "d_ff": 16, "vocab_size": 10,
          "mlp_activation": "swiglu", "dtype": "bfloat16"}
+    dense = harness.family(harness.ROOT / "bench" / "families" / "dense.py")
     # q 8*4*2 + k,v 2*8*2*2 + o 4*2*8 ; mlp 3*8*16
-    assert work.layer_matmul_params(m) == 64 + 64 + 64 + 384
+    assert dense.layer_matmul_params(m) == 64 + 64 + 64 + 384
     # 4 heads * 2 dims * 4 * (1*10 + 1)
     assert work.attention_flops(m, 10, 1) == 4 * 4 * 2 * 11
-    assert work.token_flops(m, 10) == 2 * (2 * 576 + 352) + 2 * 8 * 10
+    assert dense.token_flops(m, 10) == 2 * (2 * 576 + 352) + 2 * 8 * 10
     fl, by = work.paged_attention_work(m, [(10, 1), (0, 3)], "bfloat16")
+    assert dense.attention_work(m, [(10, 1), (0, 3)], "bfloat16") \
+        == [(fl, by)] * 2
     assert fl == 4 * 4 * 2 * (11 + 6)
     # cached K+V, the chunk's K+V, queries and output, 2 bytes each
     assert by == 2 * (10 * 2 * 2 * 2) + (2 * 1 * 2 * 2 * 2 + 2 * 1 * 4 * 2 * 2) \
